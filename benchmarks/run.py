@@ -155,6 +155,8 @@ def main(argv=None):
                    help="tag for the tracked repo-root BENCH_<tag>.json copy "
                         "(default: short git revision)")
     args = p.parse_args(argv)
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         return smoke(args.smoke_out, tag=args.tag)
     picked = args.only or SUITES

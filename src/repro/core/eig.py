@@ -23,14 +23,17 @@ def approx_eigh(C: jnp.ndarray, U: jnp.ndarray, k: int) -> EigResult:
     C = U_C Σ_C V_C^T;  Z = (Σ_C V_C^T) U (Σ_C V_C^T)^T = V_Z Λ V_Z^T;
     then C U C^T = (U_C V_Z) Λ (U_C V_Z)^T.
     """
+    def mm(a, b):
+        return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
     C32 = C.astype(jnp.float32)
     Uc, sc, Vct = jnp.linalg.svd(C32, full_matrices=False)
-    M = (sc[:, None] * Vct) @ U.astype(jnp.float32) @ (sc[:, None] * Vct).T
+    M = mm(mm(sc[:, None] * Vct, U.astype(jnp.float32)), (sc[:, None] * Vct).T)
     M = 0.5 * (M + M.T)
     lam, Vz = jnp.linalg.eigh(M)                     # ascending
     lam = lam[::-1]
     Vz = Vz[:, ::-1]
-    vecs = Uc @ Vz
+    vecs = mm(Uc, Vz)
     return EigResult(eigenvalues=lam[:k], eigenvectors=vecs[:, :k])
 
 

@@ -1,6 +1,7 @@
 """Leverage scores and coherence (paper §2)."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -80,7 +81,8 @@ def pinv(A: jnp.ndarray, rcond: float = None) -> jnp.ndarray:
     u, s, vt = jnp.linalg.svd(A32, full_matrices=False)
     cutoff = rcond * jnp.max(s)
     sinv = jnp.where(s > cutoff, 1.0 / s, 0.0)
-    return (vt.T * sinv[None, :]) @ u.T
+    return jnp.matmul(vt.T * sinv[None, :], u.T,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def orthonormal_basis(A: jnp.ndarray, rcond: float = None) -> jnp.ndarray:

@@ -37,6 +37,13 @@ from repro.core.leverage import pinv, row_leverage_scores
 # "auto" policy switches to the streaming estimators.
 _DENSE_N_CUTOFF = 2048
 
+
+def _mm(a, b):
+    """f32 product at full f32 precision (a TPU's default f32 matmul is one
+    bf16 pass, ~1e-3 relative)."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 # Seed used when a randomized estimator (Hutchinson probes, subspace
 # iteration) is called with ``key=None``.  Deliberate and documented: the
 # default path is deterministic across runs/processes so error trajectories
@@ -57,10 +64,10 @@ class SPSDApprox(NamedTuple):
     P_indices: Optional[jnp.ndarray] = None   # columns of K forming C (if sampled)
 
     def dense(self) -> jnp.ndarray:
-        return self.C @ self.U @ self.C.T
+        return _mm(_mm(self.C, self.U), self.C.T)
 
     def matmat(self, V: jnp.ndarray) -> jnp.ndarray:
-        return self.C @ (self.U @ (self.C.T @ V))
+        return _mm(self.C, _mm(self.U, _mm(self.C.T, V)))
 
 
 # ---------------------------------------------------------------------------
@@ -93,7 +100,7 @@ def fast_U(StC: jnp.ndarray, StKS: jnp.ndarray) -> jnp.ndarray:
     StC: (s, c), StKS: (s, s).  Cost O(s²c) — independent of n.
     """
     StCp = pinv(StC)                      # (c, s)
-    return StCp @ StKS.astype(StCp.dtype) @ StCp.T
+    return _mm(_mm(StCp, StKS.astype(StCp.dtype)), StCp.T)
 
 
 # ---------------------------------------------------------------------------
@@ -448,7 +455,7 @@ def _blocked_residual_fro2(Kop: SPSDOperator, approx: SPSDApprox,
     ``error_vs_best_rank_k``); their results come back in order.
     """
     C32 = approx.C.astype(jnp.float32)
-    M = approx.U.astype(jnp.float32) @ C32.T              # (c, n)
+    M = _mm(approx.U.astype(jnp.float32), C32.T)          # (c, n)
     *extras, (num, den) = Kop.sweep(
         [*extra_plans, sweep_lib.ResidualFroPlan(C32, M)],
         block_size=block_size, mesh=mesh)

@@ -36,14 +36,14 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.4.35 re-export; fall back to the experimental home
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover - depends on jax version
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 # Row panels are capped at roughly this many f32 elements (b·ncols), so the
 # streaming paths stay ~128 MB regardless of problem size.
 PANEL_ELEMENT_BUDGET = 1 << 25
+
+# Plans contract f32 panels at full f32 precision: a TPU's default f32 matmul
+# is one bf16 pass, which would put the panel route ~1e-3 off the fused one.
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def panel_block_size(ncols: int, block_size: Optional[int]) -> int:
@@ -116,7 +116,8 @@ class MatmulPlan:
         return jnp.zeros((nrows, self.V.shape[1]), jnp.float32)
 
     def update(self, carry, panel, idx, valid):
-        y = panel.astype(jnp.float32) @ self.V.astype(jnp.float32)
+        y = jnp.matmul(panel.astype(jnp.float32), self.V.astype(jnp.float32),
+                       precision=_HIGHEST)
         return carry.at[idx].add(y * valid.astype(jnp.float32)[:, None])
 
     def finalize(self, carry):
@@ -244,7 +245,8 @@ class ResidualFroPlan:
 
     def update(self, carry, panel, idx, valid):
         p32 = panel.astype(jnp.float32)
-        resid = p32 - jnp.take(self.C, idx, axis=0) @ self.M
+        resid = p32 - jnp.matmul(jnp.take(self.C, idx, axis=0), self.M,
+                                 precision=_HIGHEST)
         v = valid.astype(jnp.float32)[:, None]
         return (carry[0] + jnp.sum(resid * resid * v),
                 carry[1] + jnp.sum(p32 * p32 * v))
@@ -288,7 +290,8 @@ class ProjResidualColNormPlan:
             rowm = rowm * jnp.take(self.mask.astype(jnp.float32), idx)
         p32 = panel.astype(jnp.float32) * rowm[:, None]
         colnorms = colnorms + jnp.sum(p32 * p32, axis=0)
-        QtK = QtK + jnp.take(self.Q, idx, axis=0).T @ p32
+        QtK = QtK + jnp.matmul(jnp.take(self.Q, idx, axis=0).T, p32,
+                               precision=_HIGHEST)
         return (colnorms, QtK)
 
     def finalize(self, carry):
@@ -315,7 +318,7 @@ class GramPlan:
 
     def update(self, carry, panel, idx, valid):
         p32 = panel.astype(jnp.float32) * valid.astype(jnp.float32)[:, None]
-        return carry + p32.T @ p32
+        return carry + jnp.matmul(p32.T, p32, precision=_HIGHEST)
 
     def finalize(self, carry):
         return carry
@@ -340,7 +343,7 @@ class RowQuadFormPlan:
 
     def update(self, carry, panel, idx, valid):
         p32 = panel.astype(jnp.float32)
-        q = jnp.sum((p32 @ self.W) * p32, axis=1)
+        q = jnp.sum(jnp.matmul(p32, self.W, precision=_HIGHEST) * p32, axis=1)
         return carry.at[idx].add(q * valid.astype(jnp.float32))
 
     def finalize(self, carry):
@@ -541,9 +544,11 @@ def sweep_panels(panel_fn, nrows: int, ncols: int, plans: Sequence,
             return jax.tree_util.tree_map(
                 lambda x: jax.lax.psum(x, axes), carry)
 
-        carry = _shard_map(sharded, mesh=mesh,
-                           in_specs=P(axes), out_specs=P(),
-                           check_rep=False)(starts)
+        # check_vma=False: the Pallas launch inside a slab claim carries no
+        # varying-manual-axes annotation for the checker to follow
+        carry = jax.shard_map(sharded, mesh=mesh,
+                              in_specs=P(axes), out_specs=P(),
+                              check_vma=False)(starts)
     else:
         carry = local_carry(starts, nblocks)
     return [p.finalize(c) for p, c in zip(plans, carry)]

@@ -334,22 +334,20 @@ def replicated(mesh: Mesh):
 
 
 # ---------------------------------------------------------------------------
-# in-graph helpers (used by model code under an ambient `with mesh:`)
+# in-graph helpers (used by model code under an ambient
+# ``with jax.set_mesh(mesh):``)
 # ---------------------------------------------------------------------------
 
 def ambient_axis_size(name: str) -> int:
-    """Size of a mesh axis in the ambient (context-manager) mesh, else 1."""
-    try:
-        from jax._src import mesh as _mesh_lib
-        shape = _mesh_lib.thread_resources.env.physical_mesh.shape
-        return dict(shape).get(name, 1)
-    except Exception:                                         # noqa: BLE001
-        return 1
+    """Size of a mesh axis in the ambient (``jax.set_mesh``) mesh; 1 when no
+    mesh is set or the mesh has no such axis."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return 1 if mesh.empty else dict(mesh.shape).get(name, 1)
 
 
 def constrain(x, spec: P):
-    """with_sharding_constraint when an ambient mesh can resolve it."""
-    try:
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:                                         # noqa: BLE001
+    """``with_sharding_constraint`` under the ambient mesh; the identity when
+    no mesh is set (single-device code paths)."""
+    if jax.sharding.get_abstract_mesh().empty:
         return x
+    return jax.lax.with_sharding_constraint(x, spec)

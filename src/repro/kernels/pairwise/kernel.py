@@ -20,15 +20,17 @@ Statistics (``KernelSpec.stat``):
 
 - ``'dot'``     xᵀy — one MXU contraction.
 - ``'sqdist'``  ‖x−y‖₂² — MXU cross term + VPU norms/combine.
-- ``'l1dist'``  ‖x−y‖₁ — with a sign-split segment table (``edges``) two MXU
-  contractions over per-point segment embeddings built in VMEM
+- ``'l1dist'``  ‖x−y‖₁ — with a sign-split segment table (``edges``, turned
+  into a lane-dense slot table before the launch) two MXU contractions over
+  per-point segment embeddings built in VMEM
   (``repro.kernels.pairwise.signsplit``); without one, the reference VPU
   ``fori_loop`` over the feature axis (live set independent of d).
 
 Precision (``KernelSpec.precision``): point tiles and the kernel tile are
 quantized to ``spec.tile_dtype()`` (bf16 under ``bf16_f32acc``); every MXU
-contraction accumulates f32 via ``preferred_element_type``; ``entry_fn``
-always sees an f32 statistic.  The dense fallback (``specs.stat_block``)
+contraction accumulates f32 via ``preferred_element_type``, and f32 operands
+contract at ``Precision.HIGHEST``; ``entry_fn`` always sees an f32
+statistic.  The dense fallback (``specs.stat_block``)
 applies the identical policy, so routes stay comparable per mode.
 
 Output tiles are (128, 128) MXU/lane aligned; HBM traffic stays
@@ -44,14 +46,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pairwise.specs import KernelSpec, stat_block
+from repro.kernels.pairwise import signsplit
+from repro.kernels.pairwise.specs import KernelSpec, f32_precision, stat_block
 
 BLOCK_R = 128
 BLOCK_C = 128
 
 
 def _entry_tile(xr_ref, xc_ref, spec: KernelSpec,
-                e_ref=None) -> jnp.ndarray:
+                b_ref=None) -> jnp.ndarray:
     """One (BLOCK_R, BLOCK_C) f32 tile of kernel entries from two VMEM point
     tiles.  The statistic math is shared verbatim with the dense fallback
     (``specs.stat_block``: MXU contractions for dot/sqdist and the
@@ -62,18 +65,20 @@ def _entry_tile(xr_ref, xc_ref, spec: KernelSpec,
     dt = spec.tile_dtype()
     xr = xr_ref[...].astype(dt)
     xc = xc_ref[...].astype(dt)
-    edges = e_ref[...] if e_ref is not None else None
+    bounds = b_ref[...] if b_ref is not None else None
     return spec.entry_fn(
-        stat_block(spec.stat, xr, xc, spec.precision, edges))
+        stat_block(spec.stat, xr, xc, spec.precision, bounds))
 
 
 def _contract_tile(k_tile, v_ref, spec: KernelSpec) -> jnp.ndarray:
     """K-tile × V-tile under the precision policy: operands quantized to the
-    tile dtype, f32 partial sums on the MXU."""
+    tile dtype, f32 partial sums on the MXU (``Precision.HIGHEST`` for f32
+    operands)."""
     dt = spec.tile_dtype()
     return jax.lax.dot_general(
         k_tile.astype(dt), v_ref[...].astype(dt),
         dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=f32_precision(dt),
         preferred_element_type=jnp.float32,
     )
 
@@ -84,12 +89,12 @@ def _pairwise_block_kernel(xr_ref, xc_ref, *refs, spec: KernelSpec,
 
     xr_ref: (BLOCK_R, d) VMEM tile of row points
     xc_ref: (BLOCK_C, d) VMEM tile of column points
-    refs:   optional (d, B−1) sign-split edge table, then the
+    refs:   optional (2, B·d) sign-split slot table, then the
             (BLOCK_R, BLOCK_C) VMEM output tile
     """
-    e_ref = refs[0] if has_edges else None
+    b_ref = refs[0] if has_edges else None
     o_ref = refs[-1]
-    o_ref[...] = _entry_tile(xr_ref, xc_ref, spec, e_ref)
+    o_ref[...] = _entry_tile(xr_ref, xc_ref, spec, b_ref)
 
 
 def _pairwise_matmat_multi_kernel(xr_ref, xc_ref, *refs, spec: KernelSpec,
@@ -98,11 +103,11 @@ def _pairwise_matmat_multi_kernel(xr_ref, xc_ref, *refs, spec: KernelSpec,
 
     The (BLOCK_R, BLOCK_C) kernel tile is produced once and immediately
     contracted against every (BLOCK_C, m_i) right-hand tile while still in
-    VMEM.  ``refs`` is an optional edge-table ref, then ``nv`` V refs, then
+    VMEM.  ``refs`` is an optional slot-table ref, then ``nv`` V refs, then
     ``nv`` output accumulator refs; the column-tile grid axis j walks the
     contraction.
     """
-    e_ref = refs[0] if has_edges else None
+    b_ref = refs[0] if has_edges else None
     refs = refs[1:] if has_edges else refs
     v_refs, o_refs = refs[:nv], refs[nv:]
     j = pl.program_id(1)
@@ -112,16 +117,16 @@ def _pairwise_matmat_multi_kernel(xr_ref, xc_ref, *refs, spec: KernelSpec,
         for o_ref in o_refs:
             o_ref[...] = jnp.zeros_like(o_ref)
 
-    k_tile = _entry_tile(xr_ref, xc_ref, spec, e_ref)
+    k_tile = _entry_tile(xr_ref, xc_ref, spec, b_ref)
     for v_ref, o_ref in zip(v_refs, o_refs):
         o_ref[...] += _contract_tile(k_tile, v_ref, spec)
 
 
-def _edge_in_spec(edges, extra_grid_args: int = 0):
-    """BlockSpec broadcasting the whole (d, B−1) edge table to every tile."""
+def _bounds_in_spec(bounds, extra_grid_args: int = 0):
+    """BlockSpec broadcasting the whole (2, B·d) slot table to every tile."""
     if extra_grid_args:
-        return pl.BlockSpec(edges.shape, lambda i, j, *_: (0, 0))
-    return pl.BlockSpec(edges.shape, lambda i, j: (0, 0))
+        return pl.BlockSpec(bounds.shape, lambda i, j, *_: (0, 0))
+    return pl.BlockSpec(bounds.shape, lambda i, j: (0, 0))
 
 
 def pairwise_matmat_multi_padded(spec: KernelSpec, Xr: jnp.ndarray,
@@ -151,8 +156,9 @@ def pairwise_matmat_multi_padded(spec: KernelSpec, Xr: jnp.ndarray,
     ]
     operands = [Xr, Xc]
     if has_edges:
-        in_specs.append(_edge_in_spec(edges))
-        operands.append(edges)
+        bounds = signsplit.slot_bounds(edges)
+        in_specs.append(_bounds_in_spec(bounds))
+        operands.append(bounds)
     in_specs += [
         pl.BlockSpec((BLOCK_C, V.shape[1]), lambda i, j: (j, 0))
         for V in Vs
@@ -213,8 +219,9 @@ def pairwise_matmat_multi_slab(spec: KernelSpec, X: jnp.ndarray,
     operands = [X, X]
     has_edges = edges is not None
     if has_edges:
-        in_specs.append(_edge_in_spec(edges, extra_grid_args=1))
-        operands.append(edges)
+        bounds = signsplit.slot_bounds(edges)
+        in_specs.append(_bounds_in_spec(bounds, extra_grid_args=1))
+        operands.append(bounds)
     in_specs += [
         pl.BlockSpec((BLOCK_C, V.shape[1]), lambda i, j, off_ref: (j, 0))
         for V in Vs
@@ -253,8 +260,9 @@ def pairwise_block_padded(spec: KernelSpec, Xr: jnp.ndarray, Xc: jnp.ndarray,
     ]
     operands = [Xr, Xc]
     if has_edges:
-        in_specs.append(_edge_in_spec(edges))
-        operands.append(edges)
+        bounds = signsplit.slot_bounds(edges)
+        in_specs.append(_bounds_in_spec(bounds))
+        operands.append(bounds)
     return pl.pallas_call(
         functools.partial(_pairwise_block_kernel, spec=spec,
                           has_edges=has_edges),

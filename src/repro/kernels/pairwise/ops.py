@@ -92,6 +92,7 @@ def _kernel_matmat_multi_rows_jit(Xr: jnp.ndarray, Xc: jnp.ndarray, Vs,
         return tuple(
             jax.lax.dot_general(K.astype(dt), V.astype(dt),
                                 dimension_numbers=(((1,), (0,)), ((), ())),
+                                precision=_specs.f32_precision(dt),
                                 preferred_element_type=jnp.float32)
             for V in Vs)
     nr = Xr.shape[0]
@@ -141,6 +142,7 @@ def _kernel_matmat_multi_slab_jit(X: jnp.ndarray, start_row, Vs, edges,
         return tuple(
             jax.lax.dot_general(K.astype(dt), V.astype(dt),
                                 dimension_numbers=(((1,), (0,)), ((), ())),
+                                precision=_specs.f32_precision(dt),
                                 preferred_element_type=jnp.float32)
             for V in Vs)
     ms = [V.shape[1] for V in Vs]

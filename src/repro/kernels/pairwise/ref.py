@@ -8,9 +8,16 @@ them.  Small shapes only: every oracle materializes the full block.
 from __future__ import annotations
 # repro: allow-file(RPR003: dense f32 oracles — operands are cast to f32 before every contraction)
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels.pairwise.specs import KernelSpec
+
+
+def _mm(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """f32 product at full f32 precision, so the oracles hold on a TPU too
+    (its default f32 matmul is one bf16 pass)."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
 
 
 def _sq(Xr: jnp.ndarray, Xc: jnp.ndarray) -> jnp.ndarray:
@@ -18,7 +25,7 @@ def _sq(Xr: jnp.ndarray, Xc: jnp.ndarray) -> jnp.ndarray:
     Xc = Xc.astype(jnp.float32)
     rr = jnp.sum(Xr * Xr, axis=1)
     cc = jnp.sum(Xc * Xc, axis=1)
-    return jnp.maximum(rr[:, None] + cc[None, :] - 2.0 * (Xr @ Xc.T), 0.0)
+    return jnp.maximum(rr[:, None] + cc[None, :] - 2.0 * _mm(Xr, Xc.T), 0.0)
 
 
 def rbf_block(Xr: jnp.ndarray, Xc: jnp.ndarray, sigma: float) -> jnp.ndarray:
@@ -48,13 +55,13 @@ def polynomial_block(Xr: jnp.ndarray, Xc: jnp.ndarray, degree: int = 3,
                      coef0: float = 1.0) -> jnp.ndarray:
     """K[ri, cj] = (gamma x_ri . x_cj + coef0)^degree."""
     g = 1.0 if gamma is None else gamma
-    dot = Xr.astype(jnp.float32) @ Xc.astype(jnp.float32).T
+    dot = _mm(Xr.astype(jnp.float32), Xc.astype(jnp.float32).T)
     return (g * dot + coef0) ** degree
 
 
 def linear_block(Xr: jnp.ndarray, Xc: jnp.ndarray) -> jnp.ndarray:
     """K[ri, cj] = x_ri . x_cj."""
-    return Xr.astype(jnp.float32) @ Xc.astype(jnp.float32).T
+    return _mm(Xr.astype(jnp.float32), Xc.astype(jnp.float32).T)
 
 
 _ORACLES = {
@@ -79,10 +86,10 @@ def kernel_matmat_multi_rows(spec: KernelSpec, Xr: jnp.ndarray,
                              Xc: jnp.ndarray, Vs):
     """Rectangular row-slab oracle: [K(Xr, Xc) @ V for V in Vs]."""
     K = kernel_block(spec, Xr, Xc)
-    return tuple(K @ V.astype(jnp.float32) for V in Vs)
+    return tuple(_mm(K, V.astype(jnp.float32)) for V in Vs)
 
 
 def kernel_matmat(spec: KernelSpec, X: jnp.ndarray,
                   V: jnp.ndarray) -> jnp.ndarray:
     """K(X, X) @ V oracle (materializes K — small shapes only)."""
-    return kernel_block(spec, X, X) @ V.astype(jnp.float32)
+    return _mm(kernel_block(spec, X, X), V.astype(jnp.float32))

@@ -17,10 +17,10 @@ Derivation (per scalar u, v with segment indices i(u), i(v)):
     1[i(u) > i(v)]·u = Σ_s (u·δ_s(u))·L_s(v)       δ_s(u) = 1[i(u) = s]
     1[i(u) > i(v)]·v = Σ_s δ_s(u)·(v·L_s(v))       L_s(v) = 1[i(v) < s]
 
-so with per-point embeddings over (feature × segment) slots
+so with per-point embeddings over (segment × feature) slots
 
-    α(u) = ⊕_s ( u·δ_s(u), −δ_s(u) )               (d·2B dims)
-    β(v) = ⊕_s ( L_s(v),  v·L_s(v) )               (d·2B dims)
+    α(u) = ( ⊕_s u·δ_s(u),  ⊕_s −δ_s(u) )           (2·B·d dims)
+    β(v) = ( ⊕_s L_s(v),    ⊕_s v·L_s(v) )          (2·B·d dims)
 
 the cross-segment part of the distance is two MXU contractions:
 
@@ -39,8 +39,11 @@ quantized/standardized pipelines hit it by construction.
 Cost model per (R × C) tile: 2 contractions of inner dimension 2·d·B on the
 MXU plus O((R + C)·d·B) VPU embedding work, versus the reference route's
 d-step VPU loop over (R × C) tiles.  HBM traffic is unchanged — embeddings
-are built in VMEM from the raw (tile × d) point tiles and the shared (d, B−1)
-edge table; nothing of size n·d·B ever exists.
+are built in VMEM from the raw (tile × d) point tiles and the shared
+(2, B·d) slot table; nothing of size n·d·B ever exists.  Every embedding is
+a lane-dense 2-D (tile × B·d) array, so its VMEM footprint is what its
+shape says; ``build_plan`` refuses plans wider than ``MAX_SLOTS``, which
+keeps a tile body's embeddings inside the TPU's scoped VMEM.
 """
 from __future__ import annotations
 
@@ -56,6 +59,12 @@ import numpy as np
 #: covering the small-integer / categorical cardinalities the laplacian
 #: evaluation datasets actually have.
 MAX_SEGMENTS = 32
+
+#: widest plan (d·B slots per embedding half) the tile body may build.  The
+#: embeddings are lane-dense (tile × d·B) arrays, so a tile's VMEM grows
+#: with d·B; a v5e compile at 4,096 slots (d=128, B=32, both precisions)
+#: passes.  Wider plans are refused and the data keeps the VPU route.
+MAX_SLOTS = 4096
 
 
 @jax.tree_util.register_pytree_node_class
@@ -90,9 +99,10 @@ def build_plan(X, max_segments: int = MAX_SEGMENTS) -> Optional[SignSplitPlan]:
     Host-side (numpy) one-time O(n·d log n) pass: per feature, the sorted
     distinct values; edges at consecutive midpoints.  Returns None — caller
     keeps the VPU reference route — when any feature has more than
-    ``max_segments`` distinct values (continuous data), or when ``X`` is a
-    tracer (plans cannot be built under jit/vmap; the VPU route is always
-    safe there).
+    ``max_segments`` distinct values (continuous data), when the plan would
+    need more than ``MAX_SLOTS`` embedding slots (d·B: the tile body would
+    not fit the TPU's VMEM), or when ``X`` is a tracer (plans cannot be
+    built under jit/vmap; the VPU route is always safe there).
     """
     if isinstance(X, jax.core.Tracer):
         return None
@@ -107,6 +117,8 @@ def build_plan(X, max_segments: int = MAX_SEGMENTS) -> Optional[SignSplitPlan]:
             return None
         per_feature.append((u[:-1] + u[1:]) / 2.0)
     width = max(max(len(m) for m in per_feature), 1)
+    if d * (width + 1) > MAX_SLOTS:
+        return None
     edges = np.full((d, width), np.inf, np.float32)
     for k, m in enumerate(per_feature):
         edges[k, :len(m)] = m
@@ -141,51 +153,74 @@ def query_in_plan(X, Xq) -> bool:
                for k in range(Xh.shape[1]))
 
 
-def embed(X: jnp.ndarray, edges: jnp.ndarray,
+def slot_bounds(edges: jnp.ndarray) -> jnp.ndarray:
+    """The (2, B·d) slot table a tile body embeds against, from (d, B−1)
+    edges: row 0 holds each slot's lower edge e_{s−1}[k] (−inf for s = 0),
+    row 1 its upper edge e_s[k] (+inf for the last segment), slot s·d + k.
+
+    Built outside the Pallas kernels (a transpose + reshape of the small
+    edge table), so the tile body only compares lane-dense rows."""
+    d = edges.shape[0]
+    edges = edges.astype(jnp.float32)
+    ninf = jnp.full((d, 1), -jnp.inf, jnp.float32)
+    pinf = jnp.full((d, 1), jnp.inf, jnp.float32)
+    lo = jnp.concatenate([ninf, edges], axis=1)          # (d, B)
+    hi = jnp.concatenate([edges, pinf], axis=1)
+    return jnp.stack([lo.T.reshape(-1), hi.T.reshape(-1)])
+
+
+def embed(X: jnp.ndarray, bounds: jnp.ndarray,
           compute_dtype=jnp.float32) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """(α, β) sign-split embeddings, each (m, d·2B), from points (m, d).
+    """(α, β) sign-split embeddings, each (m, 2·B·d), from points (m, d)
+    and a ``slot_bounds`` table.
 
     Pure jnp and shape-static, so it runs identically inside the Pallas tile
-    body (point tiles in VMEM, edge table broadcast to every tile) and in the
-    dense parity oracle.  Segment indicators are computed in f32 regardless
-    of ``compute_dtype`` (they are exact 0/1 decisions); the value-carrying
-    slots are cast to ``compute_dtype`` so the bf16 tile policy quantizes
-    exactly the same numbers the reference route quantizes.
+    body (point tiles in VMEM, slot table broadcast to every tile) and in
+    the dense parity oracle.  Every intermediate is 2-D and lane-dense: X is
+    tiled B times along lanes so slot s·d + k holds x_k.  Segment indicators
+    are computed in f32 regardless of ``compute_dtype`` (they are exact 0/1
+    decisions); the value-carrying slots are cast to ``compute_dtype`` so
+    the bf16 tile policy quantizes exactly the same numbers the reference
+    route quantizes.
     """
-    m, d = X.shape
-    nseg = edges.shape[1] + 1
-    X32 = X.astype(jnp.float32)
-    ge = (X32[:, :, None] >= edges[None, :, :]).astype(jnp.float32)
-    ones = jnp.ones((m, d, 1), jnp.float32)
-    zeros = jnp.zeros((m, d, 1), jnp.float32)
-    # delta_s = 1[x >= e_{s-1}]·1[x < e_s] with e_{-1} = −inf, e_{B-1} = +inf;
-    # L_s = 1[segment(x) < s] = 1[x < e_{s-1}]
-    delta = jnp.concatenate([ones, ge], axis=2) * \
-        jnp.concatenate([1.0 - ge, ones], axis=2)
-    L = jnp.concatenate([zeros, 1.0 - ge], axis=2)
-    xv = X32[:, :, None]
-    alpha = jnp.concatenate([xv * delta, -delta], axis=2)
-    beta = jnp.concatenate([L, xv * L], axis=2)
-    alpha = alpha.reshape(m, d * 2 * nseg).astype(compute_dtype)
-    beta = beta.reshape(m, d * 2 * nseg).astype(compute_dtype)
-    return alpha, beta
+    d = X.shape[1]
+    nseg = bounds.shape[1] // d
+    xv = jnp.tile(X.astype(jnp.float32), (1, nseg))       # (m, B·d)
+    above = (xv >= bounds[0:1, :]).astype(jnp.float32)   # x ≥ e_{s−1}
+    below = (xv < bounds[1:2, :]).astype(jnp.float32)    # x < e_s
+    delta = above * below                                # δ_s(x)
+    L = 1.0 - above                                      # L_s(x)
+    alpha = jnp.concatenate([xv * delta, -delta], axis=1)
+    beta = jnp.concatenate([L, xv * L], axis=1)
+    return alpha.astype(compute_dtype), beta.astype(compute_dtype)
 
 
-def l1dist(Xr: jnp.ndarray, Xc: jnp.ndarray, edges: jnp.ndarray,
-           compute_dtype=jnp.float32) -> jnp.ndarray:
-    """Pairwise ‖x−y‖₁ via the sign-split MXU form (two contractions).
+def l1dist_slots(Xr: jnp.ndarray, Xc: jnp.ndarray, bounds: jnp.ndarray,
+                 compute_dtype=jnp.float32) -> jnp.ndarray:
+    """Pairwise ‖x−y‖₁ via the sign-split MXU form (two contractions) over
+    a ``slot_bounds`` table.
 
     The SHARED implementation of the MXU route: ``kernel._entry_tile`` calls
     this on VMEM point tiles and the dense/oracle paths call it on whole
     blocks, so the Pallas and non-Pallas sign-split routes can never diverge.
     Accumulation is always f32 (``preferred_element_type``); only the
-    operand tiles follow ``compute_dtype``.
+    operand tiles follow ``compute_dtype``, and f32 operands contract at
+    ``Precision.HIGHEST``.
     """
-    ar, br = embed(Xr, edges, compute_dtype)
-    ac, bc = embed(Xc, edges, compute_dtype)
+    ar, br = embed(Xr, bounds, compute_dtype)
+    ac, bc = embed(Xc, bounds, compute_dtype)
     dn = (((1,), (1,)), ((), ()))
-    out = jax.lax.dot_general(ar, bc, dimension_numbers=dn,
+    prec = (jax.lax.Precision.HIGHEST
+            if jnp.dtype(compute_dtype) == jnp.float32 else None)
+    out = jax.lax.dot_general(ar, bc, dimension_numbers=dn, precision=prec,
                               preferred_element_type=jnp.float32)
     out = out + jax.lax.dot_general(br, ac, dimension_numbers=dn,
+                                    precision=prec,
                                     preferred_element_type=jnp.float32)
     return jnp.maximum(out, 0.0)
+
+
+def l1dist(Xr: jnp.ndarray, Xc: jnp.ndarray, edges: jnp.ndarray,
+           compute_dtype=jnp.float32) -> jnp.ndarray:
+    """``l1dist_slots`` from a plan's (d, B−1) edge table."""
+    return l1dist_slots(Xr, Xc, slot_bounds(edges), compute_dtype)

@@ -78,6 +78,15 @@ def tile_dtype(precision: str):
     raise ValueError(f"unknown precision {precision!r}; one of {PRECISIONS}")
 
 
+def f32_precision(dtype):
+    """``Precision.HIGHEST`` for f32 operands, None (one MXU pass) otherwise.
+
+    Every contraction of the ``'f32'`` policy passes this: XLA and Mosaic
+    would otherwise contract f32 operands on the TPU in one bf16 pass.
+    """
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
+
+
 @dataclasses.dataclass(frozen=True)
 class KernelSpec:
     """One SPSD kernel family for the shared pairwise sweep template.
@@ -155,8 +164,11 @@ def dot_f32acc(Xr: jnp.ndarray, Xc: jnp.ndarray) -> jnp.ndarray:
     """Xr @ Xc.T with an f32 accumulator regardless of operand dtype — the
     one contraction primitive every tile/dense statistic routes through, so
     the bf16_f32acc policy means the same thing everywhere (bf16 operands on
-    the MXU, ``preferred_element_type=f32`` partial sums)."""
+    the MXU, ``preferred_element_type=f32`` partial sums).  f32 operands
+    contract at ``Precision.HIGHEST``: on a TPU the default f32 matmul is a
+    single bf16 pass, so the ``'f32'`` policy would otherwise not be f32."""
     return jax.lax.dot_general(Xr, Xc, dimension_numbers=_DOT_DN,
+                               precision=f32_precision(Xr.dtype),
                                preferred_element_type=jnp.float32)
 
 
@@ -181,33 +193,39 @@ def _l1dist(Xr: jnp.ndarray, Xc: jnp.ndarray) -> jnp.ndarray:
 
     The MXU default for fused launches is the sign-split decomposition
     (``signsplit.l1dist``), which needs a data-derived segment plan; this
-    loop is the plan-free fallback (continuous/high-cardinality features,
+    loop is the plan-free route (continuous/high-cardinality features,
     traced inputs) and the parity oracle the MXU route is asserted against.
     Looping the feature axis keeps the live set at one (nr, nc) f32
-    accumulator regardless of d (the broadcast form is d× that).
+    accumulator regardless of d.  Feature k is picked out with masked
+    reductions (Xr column k as (nr, 1), Xcᵀ row k as (1, nc)) rather than a
+    dynamic slice, which Mosaic cannot lower; each is exact (one nonzero
+    term per sum).
     """
     Xr = Xr.astype(jnp.float32)
-    Xc = Xc.astype(jnp.float32)
-    nr, nc = Xr.shape[0], Xc.shape[0]
+    XcT = Xc.astype(jnp.float32).T
+    nr, d = Xr.shape
+    nc = XcT.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, d), 1)
+    sublane = jax.lax.broadcasted_iota(jnp.int32, (d, 1), 0)
 
     def body(k, acc):
-        xr = jax.lax.dynamic_slice_in_dim(Xr, k, 1, axis=1)     # (nr, 1)
-        xc = jax.lax.dynamic_slice_in_dim(Xc, k, 1, axis=1)     # (nc, 1)
-        return acc + jnp.abs(xr - xc.T)
+        xr = jnp.sum(jnp.where(lane == k, Xr, 0.0), axis=1, keepdims=True)
+        xc = jnp.sum(jnp.where(sublane == k, XcT, 0.0), axis=0,
+                     keepdims=True)
+        return acc + jnp.abs(xr - xc)
 
-    return jax.lax.fori_loop(0, Xr.shape[1], body,
-                             jnp.zeros((nr, nc), jnp.float32))
+    return jax.lax.fori_loop(0, d, body, jnp.zeros((nr, nc), jnp.float32))
 
 
 def stat_block(stat: str, Xr: jnp.ndarray, Xc: jnp.ndarray,
                precision: str = "f32",
-               edges: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+               bounds: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """The (|Xr| × |Xc|) pairwise statistic (f32 out).
 
     ``precision`` quantizes the point operands (``tile_dtype``) while every
-    accumulator stays f32.  ``edges`` — a sign-split segment table — selects
-    the MXU route for ``l1dist``; without it the VPU reference loop runs.
-    Other statistics ignore ``edges``.
+    accumulator stays f32.  ``bounds`` — a sign-split slot table
+    (``signsplit.slot_bounds``) — selects the MXU route for ``l1dist``;
+    without it the VPU reference loop runs.  Other statistics ignore it.
     """
     dt = tile_dtype(precision)
     Xr = Xr.astype(dt)
@@ -217,8 +235,8 @@ def stat_block(stat: str, Xr: jnp.ndarray, Xc: jnp.ndarray,
     if stat == "sqdist":
         return _sqdist(Xr, Xc)
     if stat == "l1dist":
-        if edges is not None:
-            return signsplit.l1dist(Xr, Xc, edges, dt)
+        if bounds is not None:
+            return signsplit.l1dist_slots(Xr, Xc, bounds, dt)
         return _l1dist(Xr, Xc)
     raise ValueError(f"unknown stat {stat!r}")
 
@@ -228,8 +246,9 @@ def apply(spec: KernelSpec, Xr: jnp.ndarray, Xc: jnp.ndarray,
     """K[ri, cj] = entry_fn(stat(x_ri, x_cj)) — the dense evaluation every
     non-Pallas route (panel scans, ``full()``) runs.  Precision follows the
     spec; ``edges`` opts l1dist statistics into the MXU sign-split form."""
+    bounds = None if edges is None else signsplit.slot_bounds(edges)
     return spec.entry_fn(
-        stat_block(spec.stat, Xr, Xc, spec.precision, edges))
+        stat_block(spec.stat, Xr, Xc, spec.precision, bounds))
 
 
 def diag(spec: KernelSpec, X: jnp.ndarray) -> jnp.ndarray:

@@ -116,7 +116,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     t0 = time.time()
 
     # 1) full scanned compile: proves the cell lowers/shards + memory numbers
-    with mesh:
+    with jax.set_mesh(mesh):
         cell, compiled = _compile_cell(cfg, shape, mesh)
     t_full = time.time() - t0
 
@@ -158,7 +158,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
                                   seq_len=S1)
         sh2 = dataclasses.replace(shape, name=shape.name + "_s2",
                                   seq_len=2 * S1)
-        with mesh:
+        with jax.set_mesh(mesh):
             _, cA1 = _compile_cell(_reduced_cfg(cell.cfg, 1), sh1, mesh,
                                    accum=1)
             _, cB1 = _compile_cell(_reduced_cfg(cell.cfg, 2), sh1, mesh,
@@ -183,7 +183,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             return (out_const + out_lin * S
                     + R * (sup_lin * S + body * S))
     else:
-        with mesh:
+        with jax.set_mesh(mesh):
             _, cA = _compile_cell(_reduced_cfg(cell.cfg, 1), shape, mesh,
                                   accum=1)
             _, cB = _compile_cell(_reduced_cfg(cell.cfg, 2), shape, mesh,

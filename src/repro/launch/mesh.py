@@ -11,16 +11,21 @@ keeps 'model' collectives on adjacent ICI links.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """A mesh whose axes are all ``Auto``: GSPMD propagates shardings from
+    the annotations, as the model code and ``sharding.constrain`` expect
+    (``jax.make_mesh`` defaults to ``Explicit`` axes)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def describe(mesh) -> str:

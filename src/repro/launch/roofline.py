@@ -38,8 +38,12 @@ class HardwareProfile:
     link_bw: float               # bytes/s per ICI link (ring effective)
 
 
-#: v5e per-chip peaks (bf16 MXU) — the default target hardware
+#: v5e per-chip peaks (bf16 MXU; Google Cloud documentation, "TPU v5e")
 V5E = HardwareProfile("v5e", peak_flops=197e12, hbm_bw=819e9, link_bw=50e9)
+
+#: TPU peaks keyed by ``jax.Device.device_kind``.  A TPU that is not listed
+#: is an error, never a default.
+TPU_PROFILES = {"TPU v5 lite": V5E}
 
 #: an honest CI profile: interpret-mode Pallas on a shared CPU runner.  The
 #: numbers are order-of-magnitude host figures (a few AVX cores, DDR
@@ -50,13 +54,16 @@ CPU_INTERPRET = HardwareProfile("cpu-interpret", peak_flops=2e11,
 
 
 def default_profile() -> HardwareProfile:
-    """V5E on a TPU backend, CPU_INTERPRET everywhere else."""
-    try:
-        import jax
-        backend = jax.default_backend()
-    except Exception:  # pragma: no cover - jax always present in this repo
-        backend = "cpu"
-    return V5E if backend == "tpu" else CPU_INTERPRET
+    """The attached device's peaks: ``TPU_PROFILES[device_kind]`` on a TPU
+    (an unknown TPU raises), ``CPU_INTERPRET`` on any other backend."""
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return CPU_INTERPRET
+    if dev.device_kind not in TPU_PROFILES:
+        raise ValueError(f"no peaks recorded for TPU kind {dev.device_kind!r}; "
+                         f"known: {sorted(TPU_PROFILES)}")
+    return TPU_PROFILES[dev.device_kind]
 
 
 # Back-compat module aliases (v5e values); new code should pass a

@@ -61,7 +61,7 @@ def main(argv=None):
     mesh = parse_mesh(args.mesh)
     model = build_model(cfg)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         params = model.init(jax.random.PRNGKey(0))
         prompts = jax.random.randint(jax.random.PRNGKey(1),
                                      (args.batch, args.prompt_len), 0,

@@ -19,7 +19,7 @@ requests are queued or the oldest has waited ``max_wait_s``, then flushes —
 ``plan_buckets`` groups the batch by query count (padding bounded by
 ``waste``) and each bucket is answered by ONE ``op.cross`` launch.  Every
 request records its enqueue→complete latency; the CI serve-smoke job
-asserts the replayed trace matches the dense oracles to ≤1e-5 and that
+asserts the replayed trace matches the f64 references to ≤1e-5 and that
 ``cross_sweeps`` (via ``CountingOperator``) equals ``buckets_served``.
 
 Corpus growth rides the same loop: ``submit_append`` enqueues a training
@@ -29,7 +29,7 @@ delta checkpoint per batch, see ``repro.serve.incremental``) and swaps the
 refreshed artifact in for every later query — no rebuild, no restart.  The
 ``--append`` CLI leg replays that path and asserts the absorb was O(b·c):
 exactly one append sweep per batch, zero panel/full sweeps, and ≤1e-5
-parity against a dense f64 oracle on the GROWN corpus.
+parity against the f64 KRR reference on the GROWN corpus.
 """
 from __future__ import annotations
 
@@ -48,6 +48,7 @@ import numpy as np
 from repro import checkpoint as ckpt
 from repro.core.instrument import CountingOperator
 from repro.kernels.pairwise import specs as pw_specs
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serve import (
     GenerationStats,
     IncrementalMaintainer,
@@ -56,9 +57,9 @@ from repro.serve import (
     StalenessPolicy,
     answer_batch,
     build_artifact,
-    dense_krr_oracle,
     dense_oracle,
     is_delta_step,
+    krr_reference,
     load_artifact,
     load_or_rebuild,
     parity_gap,
@@ -331,11 +332,12 @@ def write_trace(directory: str, artifact: KernelModelArtifact, params: dict,
                 n_queries: int, seed: int) -> str:
     """Canned heterogeneous query trace + oracle-expected outputs.
 
-    KRR expectations come from ``dense_krr_oracle`` (independent dense solve
-    of the approximated kernel, f64); KPCA/feature expectations from the
-    dense-route ``dense_oracle``.  A serving process that matches this file
-    to ≤1e-5 has verified the Woodbury identity, the head algebra, the
-    fused Pallas cross launch, and checkpoint persistence at once.
+    KRR expectations come from ``krr_reference`` (an independent f64 solve
+    of the approximated kernel through a thin QR of C, never n×n, one call
+    for every KRR query); KPCA/feature expectations from the dense-route
+    ``dense_oracle``.  A serving process that matches this file to ≤1e-5
+    has verified the Woodbury identity, the head algebra, the fused Pallas
+    cross launch, and checkpoint persistence at once.
     """
     rng = np.random.default_rng(seed + 1)
     _, y = synth_problem(params["n"], params["d"], params["seed"])
@@ -343,14 +345,20 @@ def write_trace(directory: str, artifact: KernelModelArtifact, params: dict,
     tasks = [("krr", "kpca", "features")[i % 3] for i in range(n_queries)]
     payload = {"tasks": np.array(tasks), "sizes": np.array(sizes)}
     d = params["d"]
-    for i, (nq, task) in enumerate(zip(sizes, tasks)):
-        Xq = rng.standard_normal((nq, d)).astype(np.float32)
-        if task == "krr":
-            expected = dense_krr_oracle(artifact, Xq, y)
-        else:
-            expected = dense_oracle(artifact, Xq, task)
+    queries = [rng.standard_normal((nq, d)).astype(np.float32)
+               for nq in sizes]
+    krr = [i for i, task in enumerate(tasks) if task == "krr"]
+    if krr:
+        stacked = np.asarray(krr_reference(
+            artifact, np.concatenate([queries[i] for i in krr]), y))
+        bounds = np.cumsum([0] + [sizes[i] for i in krr])
+        for i, lo, hi in zip(krr, bounds[:-1], bounds[1:]):
+            payload[f"e{i}"] = stacked[lo:hi]
+    for i, (Xq, task) in enumerate(zip(queries, tasks)):
         payload[f"q{i}"] = Xq
-        payload[f"e{i}"] = np.asarray(expected, np.float32)
+        if task != "krr":
+            payload[f"e{i}"] = np.asarray(dense_oracle(artifact, Xq, task),
+                                          np.float32)
     path = os.path.join(directory, TRACE_FILE)
     np.savez(path, **payload)
     return path
@@ -515,7 +523,7 @@ def _append_leg(args, params: dict, server: KernelServer,
         print(f"FAIL: generations {gens} not consecutive in arrival order")
         ok = False
 
-    # grown-corpus parity: fresh queries vs a dense f64 oracle over the
+    # grown-corpus parity: fresh queries vs the f64 KRR reference over the
     # artifact as it NOW stands (base + every appended row)
     rng = np.random.default_rng(params["seed"] + 2)
     _, y_base = synth_problem(params["n"], params["d"], params["seed"])
@@ -525,8 +533,8 @@ def _append_leg(args, params: dict, server: KernelServer,
     gaps = []
     for nq in (5, 17, 33):
         Xq = rng.standard_normal((nq, params["d"])).astype(np.float32)
-        expected = dense_krr_oracle(art, jnp.asarray(Xq),
-                                    jnp.asarray(y_full, jnp.float32))
+        expected = krr_reference(art, jnp.asarray(Xq),
+                                 jnp.asarray(y_full, jnp.float32))
         res = server.submit(Xq, "krr").wait(timeout=60.0)
         gaps.append(float(parity_gap(res.out, expected)))
         for task in ("kpca", "features"):
@@ -599,6 +607,7 @@ def main(argv=None) -> int:
 
     if args.build == args.serve:
         p.error("exactly one of --build / --serve is required")
+    enable_compile_cache()
     return _build(args) if args.build else _serve(args)
 
 

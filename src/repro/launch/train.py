@@ -70,7 +70,7 @@ def main(argv=None):
     preempt = PreemptionHandler(install_signal=True)
     mgr = CheckpointManager(args.ckpt_dir) if args.ckpt_dir else None
 
-    with mesh:
+    with jax.set_mesh(mesh):
         params = model.init(jax.random.PRNGKey(0))
         opt_state = opt.init(params)
         psh = shd.param_shardings(params, mesh, fsdp=cfg.fsdp)

@@ -139,22 +139,17 @@ def _ep_axes(cfg):
 
 
 def _ep_axes_available(cfg) -> bool:
-    try:
-        from repro.distributed.sharding import ambient_axis_size
-        n = 1
-        for a in _ep_axes(cfg):
-            n *= ambient_axis_size(a)
-        return n > 1 and cfg.n_experts % n == 0
-    except Exception:                                         # noqa: BLE001
-        return False
+    from repro.distributed.sharding import ambient_axis_size
+    n = 1
+    for a in _ep_axes(cfg):
+        n *= ambient_axis_size(a)
+    return n > 1 and cfg.n_experts % n == 0
 
 
 def _moe_shard_map(params: dict, cfg: ModelConfig, xf: jnp.ndarray):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
-    from jax._src import mesh as _mesh_lib
 
-    mesh = _mesh_lib.thread_resources.env.physical_mesh
+    mesh = jax.sharding.get_abstract_mesh()
     axes = _ep_axes(cfg)
     dp = tuple(a for a in ("pod", "data") if a in mesh.shape)
     n_ep = 1
@@ -226,10 +221,10 @@ def _moe_shard_map(params: dict, cfg: ModelConfig, xf: jnp.ndarray):
         return out, aux
 
     ep_spec = P(axes, None, None)              # (E, d, ff): E over EP group
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(tok_spec, P(None, None), ep_spec, ep_spec, ep_spec),
         out_specs=(tok_spec, P()),
-        check_rep=False)
+        check_vma=False)
     return fn(xf, params["router"], params["wi_gate"], params["wi_up"],
               params["wo"])

@@ -23,6 +23,7 @@ from repro.serve.engine import (  # noqa: F401
     answer_batch,
     dense_krr_oracle,
     dense_oracle,
+    krr_reference,
     parity_gap,
     plan_buckets,
     serve_kernel_model,

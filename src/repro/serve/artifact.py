@@ -15,7 +15,7 @@ k̂(x, ·) = K(x, X_S) U Cᵀ (rows of C *are* K(x_i, X_S), so train points
 round-trip exactly).  The KRR weights come from the cached
 ``woodbury_solve`` route, and the (c × c) Woodbury workspace
 M = U (αI + CᵀC U)⁻¹ is kept on the artifact so re-fitting NEW targets on
-the same kernel is two thin matmuls (``refit``), never another solve.
+the same kernel is one thin matmul (``refit``), never another solve.
 
 Persistence rides ``repro.checkpoint``: the artifact flattens to a
 JSON-style dict tree (arrays + one ``meta_json`` string leaf for the
@@ -45,6 +45,23 @@ from repro.runtime.fault_tolerance import ArtifactRecovery
 
 #: the query tasks the engine can answer; head matrices are keyed by these
 TASKS = ("krr", "kpca", "features")
+
+
+def _mm(a, b):
+    """f32 product at full f32 precision (a TPU's default f32 matmul is one
+    bf16 pass)."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
+def krr_head(M, Cty):
+    """The KRR head U Cᵀ w, w = (C U Cᵀ + αI)⁻¹ y, from the Woodbury
+    workspace M = U (αI + CᵀC U)⁻¹ and Cᵀy: U Cᵀ w = M Cᵀ y.
+
+    Equal to U Cᵀ (y − C M Cᵀ y)/α, but without its cancellation: at large
+    n the top eigenvalues of C U Cᵀ reach ~1e5·α, the difference keeps only
+    the part of y they do not explain, and even in f64 the head lost ~1e-4
+    of its accuracy at n = 2^18."""
+    return M @ Cty
 
 
 @dataclasses.dataclass
@@ -101,13 +118,12 @@ class KernelModelArtifact:
 
     def refit(self, y: jnp.ndarray) -> "KernelModelArtifact":
         """New KRR targets on the SAME kernel via the cached Woodbury
-        workspace: w = (y − C M Cᵀ y)/α, head = U Cᵀ w — two thin matmuls,
-        no solve.  Returns a copy with ``heads['krr']`` replaced."""
+        workspace: head = M Cᵀ y (``krr_head``) — one thin matmul, no
+        solve.  Returns a copy with ``heads['krr']`` replaced."""
         y2 = (y[:, None] if y.ndim == 1 else y).astype(jnp.float32)
-        C32 = self.C.astype(jnp.float32)
-        w = (y2 - C32 @ (self.woodbury_M @ (C32.T @ y2))) / self.alpha
+        Cty = _mm(self.C.astype(jnp.float32).T, y2)
         heads = dict(self.heads)
-        heads["krr"] = self.U.astype(jnp.float32) @ (C32.T @ w)
+        heads["krr"] = _mm(self.woodbury_M, Cty)
         return dataclasses.replace(self, heads=heads)
 
 
@@ -210,11 +226,12 @@ def build_artifact(
     C32 = ap.C.astype(jnp.float32)
     U32 = 0.5 * (ap.U + ap.U.T).astype(jnp.float32)
 
-    # KRR: w from the Woodbury identity, workspace cached for refits.  The
-    # build-time algebra runs in f64 numpy (offline, host-side) so the f32
-    # heads it emits are true-solution-accurate — the serving parity gate
-    # (≤1e-5 vs the dense oracle) then measures only f32 rounding plus the
-    # Pallas cross launch, not solver conditioning.
+    # KRR: head = U Cᵀ w with w = (C U Cᵀ + αI)⁻¹ y from the Woodbury
+    # identity, workspace cached for refits.  The build-time algebra runs in
+    # f64 numpy (offline, host-side) so the f32 heads it emits are
+    # true-solution-accurate — the serving parity gate (≤1e-5 vs the f64
+    # reference) then measures only f32 rounding plus the Pallas cross
+    # launch, not solver conditioning.
     a = float(alpha)
     if not (a > 0.0 and np.isfinite(a)):
         raise ValueError(f"alpha must be a finite positive ridge, got {a!r}")
@@ -223,14 +240,13 @@ def build_artifact(
     inner = a * np.eye(c) + (C64.T @ C64) @ U64
     M64 = U64 @ np.linalg.solve(inner, np.eye(c))
     y64 = np.asarray(y[:, None] if y.ndim == 1 else y, np.float64)
-    w64 = (y64 - C64 @ (M64 @ (C64.T @ y64))) / a    # = woodbury_solve(C,U,a,y)
-    head_krr = jnp.asarray(U64 @ (C64.T @ w64), jnp.float32)   # (c, t)
+    head_krr = jnp.asarray(krr_head(M64, C64.T @ y64), jnp.float32)  # (c, t)
     M = jnp.asarray(M64, jnp.float32)
 
     # KPCA: z(x) = Λ^-½ Vᵀ k̂(x,·)ᵀ = K(x,X_S) · U Cᵀ V Λ^-½
     eres = eig_lib.approx_eigh(C32, U32, n_components)
     lam = jnp.maximum(eres.eigenvalues, 1e-12)
-    head_kpca = U32 @ (C32.T @ eres.eigenvectors) / jnp.sqrt(lam)[None, :]
+    head_kpca = _mm(U32, _mm(C32.T, eres.eigenvectors)) / jnp.sqrt(lam)[None, :]
 
     # Nyström feature map: U = E Λ_U Eᵀ ⇒ φ(x) = Λ_U,r^½ E_rᵀ K(x,X_S)ᵀ
     r = c if n_features is None else min(int(n_features), c)

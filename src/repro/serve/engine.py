@@ -21,6 +21,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.spsd import bucket_by_size
@@ -139,23 +140,52 @@ def serve_kernel_model(
 def dense_oracle(artifact: KernelModelArtifact, Xq: jnp.ndarray,
                  task: str = "krr") -> jnp.ndarray:
     """The non-Pallas reference: G = K(Xq, X_S) via the dense spec apply,
-    head applied in plain jnp.  KRR additionally has the independent
-    ``dense_krr_oracle`` below (no Woodbury, no artifact head)."""
+    head applied in plain jnp at full f32 precision.  KRR additionally has
+    the independent ``krr_reference`` below (no Woodbury, no artifact
+    head)."""
     from repro.kernels.pairwise import specs as pw_specs
     G = pw_specs.apply(artifact.spec, jnp.asarray(Xq, jnp.float32),
                        artifact.X_landmarks)
-    return G @ artifact.heads[task].astype(jnp.float32)
+    return jnp.matmul(G, artifact.heads[task].astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def krr_reference(artifact: KernelModelArtifact, Xq: jnp.ndarray,
+                  y: jnp.ndarray) -> jnp.ndarray:
+    """KRR on the approximated kernel in float64, at any n: neither the n×n
+    Ĉ = C U Cᵀ nor the server's Woodbury identity.
+
+    A thin QR C = Q R and the eigendecomposition R U Rᵀ = V Λ Vᵀ give
+    Ĉ = (QV) Λ (QV)ᵀ, so w = (Ĉ + αI)⁻¹ y has Qᵀ w = V (Λ + αI)⁻¹ Vᵀ Qᵀ y,
+    and the prediction k̂(x,·) w = K(x,X_S) U Cᵀ w = K(x,X_S) U Rᵀ Qᵀ w.
+    Q is never formed: Qᵀ y = R⁻ᵀ Cᵀ y.  O(n·c²) time and O(n·c) memory —
+    the serving parity reference at deployment size (``dense_krr_oracle``
+    is its small-n cross-check).  Pass every query of a trace at once: the
+    factorization is paid once per call."""
+    import numpy as np
+
+    from repro.kernels.pairwise import specs as pw_specs
+    C = np.asarray(artifact.C, np.float64)
+    U = np.asarray(artifact.U, np.float64)
+    R = np.linalg.qr(C, mode="r")
+    lam, V = np.linalg.eigh(R @ U @ R.T)
+    y2 = np.asarray(y[:, None] if y.ndim == 1 else y, np.float64)
+    Qty = np.linalg.solve(R.T, C.T @ y2)
+    Qtw = V @ ((V.T @ Qty) / (lam + artifact.alpha)[:, None])
+    G = np.asarray(
+        pw_specs.apply(artifact.spec, jnp.asarray(Xq, jnp.float32),
+                       artifact.X_landmarks), np.float64)
+    return jnp.asarray(G @ (U @ (R.T @ Qtw)), jnp.float32)
 
 
 def dense_krr_oracle(artifact: KernelModelArtifact, Xq: jnp.ndarray,
                      y: jnp.ndarray) -> jnp.ndarray:
     """End-to-end dense KRR on the approximated kernel: solve
     (C U Cᵀ + αI) w = y with a direct dense solve (no Woodbury identity),
-    then extend with k̂(x,·) = K(x,X_S) U Cᵀ.  The serving path must match
-    this to ≤1e-5 — it exercises woodbury_solve's identity, the head
-    algebra, the Pallas cross launch, and persistence in one number.  The
-    solve runs in f64 numpy (like the build-time Woodbury workspace) so the
-    parity gate measures the serving path, not solver conditioning."""
+    then extend with k̂(x,·) = K(x,X_S) U Cᵀ.  Forms the n×n Ĉ, so it is a
+    small-n test oracle only; ``krr_reference`` is the scalable equivalent.
+    The solve runs in f64 numpy (like the build-time Woodbury workspace) so
+    the parity gate measures the serving path, not solver conditioning."""
     import numpy as np
 
     from repro.kernels.pairwise import specs as pw_specs

@@ -61,6 +61,7 @@ from repro.serve.artifact import (
     KernelModelArtifact,
     artifact_from_tree,
     artifact_to_tree,
+    krr_head,
 )
 
 _TINY = 1e-30
@@ -192,19 +193,16 @@ def _refresh_heads(state: IncrementalState, artifact: KernelModelArtifact,
                    ) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray]:
     """Every head from c×c statistics (n never enters).
 
-    KRR: Cᵀw = (Cᵀy − CᵀC·M·Cᵀy)/α (the cached-workspace identity
-    ``refit`` uses, in f64), head = U·Cᵀw.
+    KRR: head = M·Cᵀy (``artifact.krr_head``, the cached-workspace
+    identity ``refit`` uses, in f64).
     KPCA: with CᵀC = V Σ² Vᵀ, Q = C V Σ⁻¹ is orthonormal and
     C U Cᵀ = Q (Σ Vᵀ U V Σ) Qᵀ — eigh of that c×c core Z is exactly the
     Lemma-10 ``approx_eigh`` spectrum, and the head
     U·CᵀVec/√Λ = U·(CᵀC·V Σ⁻¹·V_Z)/√Λ needs only CᵀC.
     Features: eigh(U) as at build time (already n-independent).
     """
-    a = state.alpha
     U64 = state.U64
-    M64 = U64 @ state.inner_inv
-    Ctw = (state.Cty - state.CtC @ (M64 @ state.Cty)) / a
-    head_krr = U64 @ Ctw
+    head_krr = krr_head(U64 @ state.inner_inv, state.Cty)
 
     k = int(artifact.heads["kpca"].shape[1])
     sig2, V = np.linalg.eigh(state.CtC)                      # ascending

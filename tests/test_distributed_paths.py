@@ -1,5 +1,6 @@
 """Multi-device correctness of the §Perf code paths (shard_map MoE EP,
-sequence-parallel attention, cache threshold rules).
+sequence-parallel attention, cache threshold rules, the sharded fused
+kernel sweep).
 
 These need >1 XLA device, which must be forced *before* jax initializes —
 so they run in a subprocess with XLA_FLAGS set (the main pytest process
@@ -15,9 +16,9 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _run(snippet: str) -> str:
+def _run(snippet: str, devices: int = 8) -> str:
     env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(snippet)],
                          capture_output=True, text=True, env=env,
@@ -31,6 +32,7 @@ def test_shard_map_moe_matches_gather():
     out = _run("""
         import dataclasses, jax, jax.numpy as jnp
         from repro.configs.base import ModelConfig
+        from repro.launch.mesh import make_mesh
         from repro.models import moe as M
         cfg = ModelConfig(name="t", family="moe", n_layers=1, d_model=64,
                           n_heads=4, n_kv_heads=4, head_dim=16, d_ff=0,
@@ -39,8 +41,8 @@ def test_shard_map_moe_matches_gather():
                           dtype="float32")
         params = M.init_moe(jax.random.PRNGKey(0), cfg)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 64)) * 0.5
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
-        with mesh:
+        mesh = make_mesh((2, 4), ("data", "model"))
+        with jax.set_mesh(mesh):
             og, ag = jax.jit(lambda p, x: M.moe_ffn(p, cfg, x))(params, x)
             c2 = dataclasses.replace(cfg, moe_impl="shard_map")
             os_, as_ = jax.jit(lambda p, x: M.moe_ffn(p, c2, x))(params, x)
@@ -60,6 +62,7 @@ def test_seq_parallel_attention_matches_baseline():
     out = _run("""
         import dataclasses, jax, jax.numpy as jnp
         from repro.configs.base import ModelConfig
+        from repro.launch.mesh import make_mesh
         from repro.models.model import build_model
         # 6 heads % 4 devices != 0 -> SP path engages on the model axis
         cfg = ModelConfig(name="t", family="dense", n_layers=2, d_model=48,
@@ -69,8 +72,8 @@ def test_seq_parallel_attention_matches_baseline():
         params = m.init(jax.random.PRNGKey(0))
         toks = jax.random.randint(jax.random.PRNGKey(1), (2, 64), 0, 64)
         batch = {"tokens": toks, "labels": jnp.roll(toks, -1, 1)}
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
-        with mesh:
+        mesh = make_mesh((2, 4), ("data", "model"))
+        with jax.set_mesh(mesh):
             l0, _ = jax.jit(m.loss)(params, batch)
             c2 = dataclasses.replace(cfg, seq_parallel_attn=True)
             m2 = build_model(c2)
@@ -93,7 +96,7 @@ def test_decode_cell_lowers_on_multidevice_mesh():
                           vocab_size=256)
         mesh = make_mesh((2, 4), ("data", "model"))
         shape = ShapeConfig("d", 256, 4, "decode")
-        with mesh:
+        with jax.set_mesh(mesh):
             cell = build_cell(cfg, shape, mesh)
             compiled = jax.jit(cell.step_fn,
                                in_shardings=cell.in_shardings,
@@ -101,4 +104,50 @@ def test_decode_cell_lowers_on_multidevice_mesh():
                 .lower(*cell.abstract_args).compile()
         print("OK", compiled is not None)
     """)
+    assert "OK" in out
+
+
+def test_sharded_fused_sweep_on_four_devices():
+    """The ``pallas_fused_sharded`` route over a 4-device data mesh: route and
+    slab mode recorded, and fast_model / fast_model_with_error agree with the
+    single-device sweep to ≤1e-5 (scale-normalized)."""
+    out = _run("""
+        import jax, jax.numpy as jnp, numpy as np
+        from repro.core import spsd
+        from repro.core.instrument import CountingOperator
+        from repro.core.kernelop import PairwiseKernel
+        from repro.distributed.sharding import data_parallel_mesh
+        from repro.kernels.pairwise import specs
+        assert len(jax.devices()) == 4
+        mesh = data_parallel_mesh()
+        X = jnp.asarray(np.random.default_rng(0).normal(size=(517, 8)),
+                        jnp.float32)
+        spec = specs.rbf(2.0)
+        key = jax.random.PRNGKey(3)
+
+        def gap(a, b):
+            a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+            return np.max(np.abs(a - b)) / max(1.0, np.max(np.abs(b)))
+
+        def run(fn, mesh, **kw):
+            op = CountingOperator(PairwiseKernel(X, spec, use_pallas=True))
+            return fn(op, key, c=24, s=96, mesh=mesh, **kw), op
+
+        for fn, kw in ((spsd.fast_model, dict(s_sketch="gaussian")),
+                       (spsd.fast_model_with_error, dict(s_sketch="uniform")),
+                       (spsd.fast_model_with_error,
+                        dict(s_sketch="gaussian"))):
+            got, op = run(fn, mesh, **kw)
+            ref, op1 = run(fn, None, **kw)
+            assert op.last_route == "pallas_fused_sharded", op.last_route
+            assert op.last_slab_mode == "prefetch", op.last_slab_mode
+            assert op1.last_route == "pallas_fused", op1.last_route
+            assert op.counts["sweeps"] == 1, op.counts
+            if fn is spsd.fast_model_with_error:
+                (got, e), (ref, e1) = got, ref
+                assert abs(float(e) - float(e1)) <= 1e-5, (e, e1)
+            assert gap(got.C, ref.C) <= 1e-5, gap(got.C, ref.C)
+            assert gap(got.U, ref.U) <= 1e-5, gap(got.U, ref.U)
+        print("OK")
+    """, devices=4)
     assert "OK" in out

@@ -246,3 +246,18 @@ def test_linear_kernel_keeps_factored_fast_paths():
                                rtol=1e-4, atol=1e-4)
     assert float(K.frobenius_norm_sq()) == pytest.approx(
         float((Kd ** 2).sum()), rel=1e-4)
+
+
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["dense", "pallas"])
+@pytest.mark.parametrize("d", [1, 5, 16, 112])
+def test_vpu_l1dist_matches_laplacian_reference(use_pallas, d):
+    """The VPU l1dist route (masked per-feature reductions, no dynamic lane
+    slice) reproduces ``ref.laplacian_block`` on continuous data, where no
+    sign-split plan exists — in the dense apply and the Pallas tile body."""
+    spec = specs.get_spec("laplacian", gamma=1.0 / d)   # entries ~ e^-1
+    X = _points(40 + d, 150, d)
+    Y = _points(41 + d, 70, d)
+    op = PairwiseKernel(X, spec)
+    assert op.l1_route() == "vpu_loop"
+    got = pw_ops.kernel_block(spec, X, Y, use_pallas=use_pallas)
+    assert_parity(got, pw_ref.laplacian_block(X, Y, spec.param("gamma")))

@@ -116,6 +116,24 @@ def test_default_profile_is_honest_about_cpu():
     assert rl.PEAK_FLOPS == rl.V5E.peak_flops
 
 
+@pytest.mark.parametrize("kind,expected", [("TPU v5 lite", "v5e"),
+                                           ("TPU v99", None)])
+def test_default_profile_keys_tpu_peaks_by_device_kind(monkeypatch, kind,
+                                                       expected):
+    """A TPU's peaks come from its ``device_kind``; an unlisted TPU raises
+    instead of borrowing another chip's numbers."""
+    import types
+
+    import jax
+    fake = types.SimpleNamespace(platform="tpu", device_kind=kind)
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [fake])
+    if expected is None:
+        with pytest.raises(ValueError, match="TPU v99"):
+            rl.default_profile()
+    else:
+        assert rl.default_profile().name == expected
+
+
 def test_pairwise_launch_model_flop_split():
     """The unit split is the point: sign-split moves l1dist work from the
     VPU bucket to the MXU bucket; the VPU loop has zero MXU stat FLOPs."""

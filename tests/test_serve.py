@@ -25,6 +25,7 @@ from repro.serve import (
     build_artifact,
     dense_krr_oracle,
     dense_oracle,
+    krr_reference,
     load_artifact,
     load_or_rebuild,
     parity_gap,
@@ -70,6 +71,41 @@ def test_krr_parity_vs_dense_solve_oracle(artifact, problem, queries):
     res = serve_kernel_model(artifact, [QueryRequest(queries, "krr")])
     expected = dense_krr_oracle(artifact, queries, y)
     assert parity_gap(res[0].out, expected) <= 1e-5
+
+
+@pytest.mark.parametrize("targets", [1, 3], ids=["vector", "matrix"])
+def test_krr_reference_matches_dense_solve_in_float64(artifact, queries,
+                                                      targets):
+    """The scalable QR/eigen KRR reference (never n×n) agrees with the
+    direct dense solve at small n, to float64 accuracy before both round to
+    their f32 outputs."""
+    y = jnp.asarray(np.random.default_rng(11).standard_normal((N, targets)),
+                    jnp.float32)
+    y = y[:, 0] if targets == 1 else y
+    got = np.asarray(krr_reference(artifact, queries, y), np.float64)
+    ref = np.asarray(dense_krr_oracle(artifact, queries, y), np.float64)
+    assert got.shape == (queries.shape[0], targets)
+    assert parity_gap(got, ref) <= 1e-6
+
+
+def test_krr_head_stays_accurate_when_the_spectrum_dwarfs_the_ridge():
+    """Clustered data and a ridge far below the top of C U Cᵀ's spectrum
+    (the regime of a large corpus): the f32 head must still reproduce the
+    f64 reference at the serving gate.  Forming Cᵀw = Cᵀ(y − C M Cᵀ y)/α
+    first loses ~1e-3 here to cancellation; ``krr_head``'s M Cᵀ y does not."""
+    rng = np.random.default_rng(5)
+    centers = rng.normal(size=(26, 16)) * 2.0
+    X = centers[rng.integers(0, 26, 2048)] + rng.normal(size=(2048, 16)) * .7
+    X = jnp.asarray((X - X.mean(0)) / X.std(0), jnp.float32)
+    y = jnp.asarray(rng.choice([-1.0, 1.0], size=(2048, 3)), jnp.float32)
+    spec = pw_specs.get_spec("rbf", sigma=3.9)
+    art = build_artifact(X, y, spec, c=256, s=1024, alpha=1e-3,
+                         s_sketch="uniform", key=jax.random.PRNGKey(0),
+                         use_pallas=False)
+    Xq = X[:50] + 0.1
+    G = np.asarray(pw_specs.apply(spec, Xq, art.X_landmarks), np.float64)
+    served = G @ np.asarray(art.heads["krr"], np.float64)
+    assert parity_gap(served, krr_reference(art, Xq, y)) <= 1e-5
 
 
 def test_kpca_and_feature_parity_vs_dense_route(artifact, queries):

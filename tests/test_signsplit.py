@@ -59,6 +59,18 @@ def test_build_plan_refuses_continuous_data():
     assert signsplit.build_plan(X) is None
 
 
+def test_build_plan_refuses_plans_wider_than_the_slot_budget():
+    """Lattice data whose d·B embedding would overflow a tile's VMEM gets no
+    plan: the VPU route is the recorded decision, not a fallback."""
+    X = _quantized(33, 64, d=300, levels=16)
+    assert 300 * 16 > signsplit.MAX_SLOTS
+    assert signsplit.build_plan(X) is None
+    spec = specs.suggested_spec("laplacian", 300)
+    assert PairwiseKernel(X, spec).l1_route() == "vpu_loop"
+    narrow = _quantized(33, 64, d=100, levels=16)
+    assert signsplit.build_plan(narrow) is not None
+
+
 def test_build_plan_refuses_tracers():
     X = _quantized(2, 64, d=4)
     seen = []

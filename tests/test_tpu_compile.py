@@ -1,0 +1,106 @@
+"""Ahead-of-time compiles of the main-path Pallas launches for a described
+TPU v5e at real widths — what Mosaic and the TPU compiler refuse (dynamic
+lane slices, VMEM overflows, misaligned blocks) shows up here, with no chip.
+
+The topology is described inside a module-scoped fixture, never at import:
+only the worker that runs these tests loads the TPU compiler, so the other
+test workers collect the same tests and never contend for it.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.pairwise import kernel as pw_kernel
+from repro.kernels.pairwise import ops as pw_ops
+from repro.kernels.pairwise import specs
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                                   # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without one; keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *shapes):
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert "tpu_custom_call" in text          # the Pallas kernel is in there
+    return text
+
+
+def _edges(d, nseg):
+    """A (d, nseg−1) sign-split edge table (values only matter at run time)."""
+    return np.tile(np.arange(nseg - 1, dtype=np.float32) + 0.5, (d, 1))
+
+
+N = 2048             # rows: grid extent only, the tile body is what compiles
+M = (512, 128)       # right-hand-side widths: C's one-hot gather + probes
+
+
+@pytest.mark.parametrize("precision", specs.PRECISIONS)
+def test_rbf_multi_rhs_launch(one_chip, precision):
+    spec = specs.rbf(4.0).with_precision(precision)
+    X = jax.ShapeDtypeStruct((N, 16), jnp.float32, sharding=one_chip)
+    Vs = tuple(jax.ShapeDtypeStruct((N, m), jnp.float32, sharding=one_chip)
+               for m in M)
+    _compile(lambda X, *Vs: pw_kernel.pairwise_matmat_multi_padded(
+        spec, X, X, Vs), X, *Vs)
+
+
+@pytest.mark.parametrize("precision", specs.PRECISIONS)
+@pytest.mark.parametrize("d,nseg", [(16, 32), (112, 8)])
+@pytest.mark.parametrize("route", ["vpu_loop", "mxu_signsplit"])
+def test_laplacian_multi_rhs_launch(one_chip, route, d, nseg, precision):
+    spec = specs.laplacian(1.0 / d).with_precision(precision)
+    edges = _edges(d, nseg) if route == "mxu_signsplit" else None
+    X = jax.ShapeDtypeStruct((N, d), jnp.float32, sharding=one_chip)
+    Vs = tuple(jax.ShapeDtypeStruct((N, m), jnp.float32, sharding=one_chip)
+               for m in M)
+    _compile(lambda X, *Vs: pw_kernel.pairwise_matmat_multi_padded(
+        spec, X, X, Vs, edges=edges), X, *Vs)
+
+
+def test_prefetch_slab_launch(one_chip):
+    spec = specs.rbf(4.0)
+    X = jax.ShapeDtypeStruct((N, 16), jnp.float32, sharding=one_chip)
+    Vs = tuple(jax.ShapeDtypeStruct((N, m), jnp.float32, sharding=one_chip)
+               for m in M)
+    off = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+    _compile(lambda X, off, *Vs: pw_kernel.pairwise_matmat_multi_slab(
+        spec, X, off, N // 4 // pw_kernel.BLOCK_R, Vs), X, off, *Vs)
+
+
+@pytest.mark.parametrize("d", [16, 112, 784])
+def test_serve_cross_launch(one_chip, d):
+    """The KernelServer's one launch per bucket: a ragged query block
+    against c=512 landmarks and three heads, with interpretation off."""
+    spec = specs.rbf(4.0)
+    Xq = jax.ShapeDtypeStruct((200, d), jnp.float32, sharding=one_chip)
+    Xs = jax.ShapeDtypeStruct((512, d), jnp.float32, sharding=one_chip)
+    heads = tuple(jax.ShapeDtypeStruct((512, m), jnp.float32,
+                                       sharding=one_chip) for m in (26, 8, 512))
+    _compile(lambda Xq, Xs, *hs: pw_ops.kernel_matmat_multi_rows(
+        spec, Xq, Xs, hs, interpret=False), Xq, Xs, *heads)

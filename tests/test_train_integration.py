@@ -108,7 +108,7 @@ def test_build_cell_on_debug_mesh():
     shape_t = ShapeConfig("t", 32, 4, "train")
     shape_p = ShapeConfig("p", 32, 4, "prefill")
     shape_d = ShapeConfig("d", 32, 4, "decode")
-    with mesh:
+    with jax.set_mesh(mesh):
         for shape in (shape_t, shape_p, shape_d):
             cell = build_cell(CFG, shape, mesh)
             jitted = jax.jit(cell.step_fn, in_shardings=cell.in_shardings,
