@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from repro.core import selection as selection_lib
 from repro.core import sketch as sk
 from repro.core import sweep as sweep_lib
+from repro.core.instrument import span
 from repro.core.kernelop import DenseSPSD, SPSDOperator, as_operator
 from repro.core.leverage import pinv, row_leverage_scores
 
@@ -94,6 +95,7 @@ def nystrom_U(W: jnp.ndarray) -> jnp.ndarray:
     return pinv(Wsym)
 
 
+@span("spsd.fast_u")
 def fast_U(StC: jnp.ndarray, StKS: jnp.ndarray) -> jnp.ndarray:
     """U^fast = (S^T C)† (S^T K S) (C^T S)†  (Eq. 5).
 
@@ -129,6 +131,7 @@ def nystrom_model(K, key: jax.Array, c: int) -> SPSDApprox:
     return SPSDApprox(C=C, U=nystrom_U(W), P_indices=idx)
 
 
+@span("spsd.sketch_block")
 def _column_sketch_for_C(Kop: SPSDOperator, C: jnp.ndarray, key: jax.Array,
                          s: int, s_sketch: str, P_indices, enforce_subset: bool,
                          scale: bool, mask: Optional[jnp.ndarray]):
@@ -282,15 +285,18 @@ def fast_model_with_error(
     more per adaptive round).  ``selection`` picks the policy that chooses
     C's columns (its declared sweeps are the only addition to the budget).
     Returns ``(approx, relative_error)`` with the same estimator as
-    ``relative_error(method="hutchinson")``.
+    ``relative_error(method="hutchinson")``.  Its phases run inside the
+    spans ``spsd.select``, ``sweep.<route>``, ``spsd.sketch_block``,
+    ``spsd.fast_u`` and ``spsd.certify`` (``instrument.span``).
     """
     Kop = as_operator(K)
     n = Kop.n
-    kc, ks = jax.random.split(key)
-    kz = jax.random.fold_in(key, 777) if error_key is None else error_key
-    pol = selection_lib.get_policy(selection)
-    idx = pol.select(Kop, kc, c, block_size=block_size, mesh=mesh)
-    Z = jax.random.rademacher(kz, (n, probes), dtype=jnp.float32)
+    with span("spsd.select"):
+        kc, ks = jax.random.split(key)
+        kz = jax.random.fold_in(key, 777) if error_key is None else error_key
+        pol = selection_lib.get_policy(selection)
+        idx = pol.select(Kop, kc, c, block_size=block_size, mesh=mesh)
+        Z = jax.random.rademacher(kz, (n, probes), dtype=jnp.float32)
 
     if s_sketch in ("uniform", "leverage"):
         C, KZ = Kop.sweep(
@@ -304,11 +310,13 @@ def fast_model_with_error(
             [sweep_lib.ColumnGatherPlan(idx), sk.plan_for_sketch(S),
              sweep_lib.MatmulPlan(Z)],
             block_size=block_size, mesh=mesh)
-        StC, StKS = S.left(C), S.left(KS)
+        with span("spsd.sketch_block"):
+            StC, StKS = S.left(C), S.left(KS)
 
     approx = SPSDApprox(C=C, U=fast_U(StC, StKS), P_indices=idx)
-    RZ = KZ.astype(jnp.float32) - approx.matmat(Z).astype(jnp.float32)
-    err = jnp.sum(RZ * RZ) / jnp.sum(KZ * KZ)
+    with span("spsd.certify"):
+        RZ = KZ.astype(jnp.float32) - approx.matmat(Z).astype(jnp.float32)
+        err = jnp.sum(RZ * RZ) / jnp.sum(KZ * KZ)
     return approx, err
 
 

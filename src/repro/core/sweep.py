@@ -402,8 +402,11 @@ def sweep_operator(op, plans: Sequence, block_size: Optional[int] = None,
     the engine's ``slab_fn`` hook, where each device runs one rectangular
     row-slab launch and the partial carries are psum-reduced exactly like the
     panel route ('pallas_fused_sharded').  Everything else walks the blocked
-    panel scan over ``op.block`` ('panel').
+    panel scan over ``op.block`` ('panel').  Each route runs inside a
+    ``span`` named ``sweep.<route>``.
     """
+    from repro.core.instrument import span   # instrument imports this module
+
     plans = list(plans)
     n = op.n
     fused = op.supports_fused_matmat() and is_matmul_shaped(plans)
@@ -415,7 +418,8 @@ def sweep_operator(op, plans: Sequence, block_size: Optional[int] = None,
     op._last_slab_mode = None          # only sharded fused claims set this
     if fused and mesh_data_size(mesh) <= 1:
         op._last_sweep_route = "pallas_fused" + suffix
-        return list(op.fused_rows(None, fused_right_hand_sides(plans, n)))
+        with span("sweep.pallas_fused"):
+            return list(op.fused_rows(None, fused_right_hand_sides(plans, n)))
     if fused:
         op._last_sweep_route = "pallas_fused_sharded" + suffix
         Vs = fused_right_hand_sides(plans, n)
@@ -438,12 +442,14 @@ def sweep_operator(op, plans: Sequence, block_size: Optional[int] = None,
                          for p, o in zip(plans, outs))
 
         # panel_fn=None: the claim is unconditional, the scan never runs
-        return sweep_panels(None, n, n, plans,
-                            block_size=block_size, mesh=mesh, slab_fn=slab_fn)
+        with span("sweep.pallas_fused_sharded"):
+            return sweep_panels(None, n, n, plans, block_size=block_size,
+                                mesh=mesh, slab_fn=slab_fn)
     op._last_sweep_route = "panel"
     cols = jnp.arange(n)
-    return sweep_panels(lambda idx: op.block(idx, cols), n, n, plans,
-                        block_size=block_size, mesh=mesh)
+    with span("sweep.panel"):
+        return sweep_panels(lambda idx: op.block(idx, cols), n, n, plans,
+                            block_size=block_size, mesh=mesh)
 
 
 # ---------------------------------------------------------------------------

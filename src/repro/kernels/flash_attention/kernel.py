@@ -105,6 +105,8 @@ def flash_attention_padded(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     return pl.pallas_call(
         kern,
+        name="flash_attention",
+        metadata={"kernel": "flash_attention"},
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),

@@ -57,6 +57,8 @@ def landmark_read_padded(Q: jnp.ndarray, k_land: jnp.ndarray,
     off2 = jnp.asarray(offset, jnp.float32).reshape(1, 1)
     return pl.pallas_call(
         functools.partial(_landmark_kernel, eps=eps),
+        name="landmark_attention",
+        metadata={"kernel": "landmark_attention"},
         grid=grid,
         in_specs=[
             pl.BlockSpec((BLOCK_Q, d), lambda i: (i, 0)),

@@ -36,10 +36,17 @@ applies the identical policy, so routes stay comparable per mode.
 Output tiles are (128, 128) MXU/lane aligned; HBM traffic stays
 O((nr + nc)·d + Σ nc·m_i + Σ nr·m_i) — the Table-3 "#Entries" story for the
 whole kernel family, not just RBF.
+
+Every launch carries a stable ``name`` (``pairwise_block``,
+``pairwise_matmat_multi``, ``pairwise_matmat_slab``) and a launch record in
+its ``metadata`` (``launch_record``): the work it issues at the shapes it
+launches with, counted by ``launch_work``.  Both land in the custom call's
+HLO text, so a device trace finds each launch and its work by name.
 """
 from __future__ import annotations
 
 import functools
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -51,6 +58,67 @@ from repro.kernels.pairwise.specs import KernelSpec, f32_precision, stat_block
 
 BLOCK_R = 128
 BLOCK_C = 128
+
+
+def launch_work(spec: KernelSpec, nr: int, nc: int, d: int, m_total: int,
+                l1_route: Optional[str] = None,
+                segments: int = 0) -> Dict[str, int]:
+    """The work ONE fused pairwise launch issues, split by unit.
+
+    ``nr × nc`` kernel entries from (nr, d) × (nc, d) points, contracted
+    against right-hand sides totalling ``m_total`` columns.  The split
+    matters because the point of the MXU-everywhere pipeline is moving work
+    from the ``vpu_flops`` bucket to the ``mxu_flops`` bucket:
+
+    - ``dot``      2d MXU FLOPs/entry.
+    - ``sqdist``   2d MXU FLOPs/entry + O(1) VPU combine (+ row norms).
+    - ``l1dist``   route-dependent — 'mxu_signsplit' pays two contractions
+      of inner dimension 2·d·B (B = ``segments``): 8·d·B MXU FLOPs/entry
+      plus O((nr+nc)·d·B) VPU embedding; 'vpu_loop' pays ~4d VPU
+      FLOPs/entry (subtract, abs, accumulate, loop bookkeeping).
+
+    The V contraction adds 2·m_total MXU FLOPs/entry; ``entry_fn`` is
+    modeled at 8 VPU FLOPs/entry (transcendental-ish).  MXU FLOPs count one
+    pass per contraction: the passes an f32 ``HIGHEST`` contraction takes
+    are not counted.  Bytes are the perfect-fusion HBM floor: points +
+    right-hand sides in, outputs out — kernel tiles never touch HBM (that
+    IS the fused template's claim).
+    """
+    entries = nr * nc
+    stat = spec.stat
+    if stat in ("dot", "sqdist"):
+        width = d
+        vpu = 4 * entries + 2 * (nr + nc) * d if stat == "sqdist" else 0
+    elif stat == "l1dist":
+        if l1_route == "mxu_signsplit":
+            inner = 2 * d * max(int(segments), 1)
+            width = 2 * inner                          # two contractions
+            vpu = 6 * (nr + nc) * inner                # VMEM embeddings
+        else:
+            width = 0
+            vpu = 4 * d * entries                      # the reference loop
+    else:  # pragma: no cover - specs validate stat
+        raise ValueError(f"unknown stat {stat!r}")
+    point_bytes = 2 if spec.precision != "f32" else 4
+    return {"entries": entries,
+            "mxu_flops": 2 * entries * (width + m_total),   # stat + K-tile @ V
+            "vpu_flops": vpu + 8 * entries,                # + entry_fn
+            "hbm_bytes": (nr + nc) * d * point_bytes + (nc + nr) * m_total * 4}
+
+
+def launch_record(kernel: str, spec: KernelSpec, nr: int, nc: int, d: int,
+                  ms, edges) -> Dict[str, str]:
+    """The ``metadata`` of one pairwise launch: its kernel name, and the MXU
+    FLOPs and kernel entries it issues at its launch shapes (rows and
+    columns padded to the tiles, each right-hand side to 128 columns, d as
+    launched), under its tile precision.  Values are strings, as
+    ``pallas_call`` requires."""
+    route = "mxu_signsplit" if edges is not None else None
+    segments = 0 if edges is None else int(edges.shape[1]) + 1
+    work = launch_work(spec, nr, nc, d, sum(ms), route, segments)
+    return {"kernel": kernel, "mxu_flops": str(work["mxu_flops"]),
+            "entries": str(work["entries"]), "precision": spec.precision,
+            "passes": "not counted"}
 
 
 def _entry_tile(xr_ref, xc_ref, spec: KernelSpec,
@@ -166,6 +234,9 @@ def pairwise_matmat_multi_padded(spec: KernelSpec, Xr: jnp.ndarray,
     return pl.pallas_call(
         functools.partial(_pairwise_matmat_multi_kernel, spec=spec,
                           nv=len(Vs), has_edges=has_edges),
+        name="pairwise_matmat_multi",
+        metadata=launch_record("pairwise_matmat_multi", spec, nr, nc, d,
+                               [V.shape[1] for V in Vs], edges),
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -238,6 +309,9 @@ def pairwise_matmat_multi_slab(spec: KernelSpec, X: jnp.ndarray,
     return pl.pallas_call(
         functools.partial(_pairwise_matmat_slab_kernel, spec=spec,
                           nv=len(Vs), has_edges=has_edges),
+        name="pairwise_matmat_slab",
+        metadata=launch_record("pairwise_matmat_slab", spec, nr, n, d,
+                               [V.shape[1] for V in Vs], edges),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((nr, V.shape[1]), jnp.float32)
                    for V in Vs],
@@ -266,6 +340,8 @@ def pairwise_block_padded(spec: KernelSpec, Xr: jnp.ndarray, Xc: jnp.ndarray,
     return pl.pallas_call(
         functools.partial(_pairwise_block_kernel, spec=spec,
                           has_edges=has_edges),
+        name="pairwise_block",
+        metadata=launch_record("pairwise_block", spec, nr, nc, d, (), edges),
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((BLOCK_R, BLOCK_C), lambda i, j: (i, j)),

@@ -26,6 +26,9 @@ import json
 import re
 from typing import Dict, List, Optional
 
+from repro.kernels.pairwise.kernel import launch_work
+
+
 @dataclasses.dataclass(frozen=True)
 class HardwareProfile:
     """Peak rates the roofline terms divide by — a PARAMETER, not a module
@@ -288,55 +291,6 @@ def analyze(compiled, hlo_text: str, *, arch: str, shape, cfg, mesh_name: str,
 # kernel-layer scoring: the pairwise sweep template's per-launch roofline
 # ---------------------------------------------------------------------------
 
-def pairwise_launch_model(spec, nr: int, nc: int, d: int, m_total: int,
-                          l1_route: Optional[str] = None,
-                          segments: int = 0) -> Dict[str, float]:
-    """Analytic FLOP/byte model of ONE fused pairwise launch, split by unit.
-
-    ``nr × nc`` kernel entries from (nr, d) × (nc, d) points, contracted
-    against right-hand sides totalling ``m_total`` columns.  The split
-    matters because the point of the MXU-everywhere pipeline is moving work
-    from the ``vpu_flops`` bucket to the ``mxu_flops`` bucket:
-
-    - ``dot``      2d MXU FLOPs/entry.
-    - ``sqdist``   2d MXU FLOPs/entry + O(1) VPU combine (+ row norms).
-    - ``l1dist``   route-dependent — 'mxu_signsplit' pays two contractions
-      of inner dimension 2·d·B (B = ``segments``): 8·d·B MXU FLOPs/entry
-      plus O((nr+nc)·d·B) VPU embedding; 'vpu_loop' pays ~4d VPU
-      FLOPs/entry (subtract, abs, accumulate, loop bookkeeping).
-
-    The V contraction adds 2·m_total MXU FLOPs/entry; ``entry_fn`` is
-    modeled at 8 VPU FLOPs/entry (transcendental-ish).  Bytes are the
-    perfect-fusion HBM floor: points + right-hand sides in, outputs out —
-    kernel tiles never touch HBM (that IS the fused template's claim).
-    """
-    entries = float(nr) * float(nc)
-    stat = spec.stat
-    if stat == "dot":
-        mxu = 2.0 * d * entries
-        vpu = 0.0
-    elif stat == "sqdist":
-        mxu = 2.0 * d * entries
-        vpu = 4.0 * entries + 2.0 * (nr + nc) * d
-    elif stat == "l1dist":
-        if l1_route == "mxu_signsplit":
-            inner = 2.0 * d * max(int(segments), 1)
-            mxu = 2.0 * 2.0 * inner * entries          # two contractions
-            vpu = 6.0 * (nr + nc) * inner              # VMEM embeddings
-        else:
-            mxu = 0.0
-            vpu = 4.0 * d * entries                    # the reference loop
-    else:  # pragma: no cover - specs validate stat
-        raise ValueError(f"unknown stat {stat!r}")
-    mxu += 2.0 * float(m_total) * entries              # K-tile @ V
-    vpu += 8.0 * entries                               # entry_fn
-    point_bytes = 2 if getattr(spec, "precision", "f32") != "f32" else 4
-    gbytes = ((nr + nc) * d * point_bytes
-              + (nc + nr) * m_total * 4.0) / 1e9
-    return {"mxu_gflops": mxu / 1e9, "vpu_gflops": vpu / 1e9,
-            "hbm_gbytes": gbytes}
-
-
 def achieved_vs_roofline(spec, shape, mesh=None, *, measured_s: float,
                          m_total: int, l1_route: Optional[str] = None,
                          segments: int = 0,
@@ -344,7 +298,9 @@ def achieved_vs_roofline(spec, shape, mesh=None, *, measured_s: float,
     """Score one measured pairwise launch against its modeled roofline.
 
     ``shape`` is ``(nr, nc, d)`` for the launch; ``mesh`` (optional) divides
-    the modeled work across its devices like the sharded sweep does.
+    the modeled work across its devices like the sharded sweep does.  The
+    work is ``launch_work``'s, the count each launch also records in its
+    metadata.
     Returns a JSON-ready report: modeled compute/memory seconds under
     ``profile`` (``default_profile()`` when omitted — so CI's CPU-interpret
     numbers are scored against CPU peaks, not v5e's), the binding term, and
@@ -357,11 +313,11 @@ def achieved_vs_roofline(spec, shape, mesh=None, *, measured_s: float,
     chips = 1
     if mesh is not None and getattr(mesh, "devices", None) is not None:
         chips = max(1, int(mesh.devices.size))
-    model = pairwise_launch_model(spec, nr, nc, d, m_total,
-                                  l1_route=l1_route, segments=segments)
-    compute_s = (model["mxu_gflops"] + model["vpu_gflops"]) * 1e9 / (
+    work = launch_work(spec, nr, nc, d, int(m_total), l1_route=l1_route,
+                       segments=segments)
+    compute_s = (work["mxu_flops"] + work["vpu_flops"]) / (
         chips * prof.peak_flops)
-    memory_s = model["hbm_gbytes"] * 1e9 / (chips * prof.hbm_bw)
+    memory_s = work["hbm_bytes"] / (chips * prof.hbm_bw)
     roofline_s = max(compute_s, memory_s)
     return {
         "kernel": spec.name,
@@ -372,7 +328,9 @@ def achieved_vs_roofline(spec, shape, mesh=None, *, measured_s: float,
         "m_total": int(m_total),
         "chips": chips,
         "profile": prof.name,
-        **{k: float(v) for k, v in model.items()},
+        "mxu_gflops": work["mxu_flops"] / 1e9,
+        "vpu_gflops": work["vpu_flops"] / 1e9,
+        "hbm_gbytes": work["hbm_bytes"] / 1e9,
         "compute_s": float(compute_s),
         "memory_s": float(memory_s),
         "bottleneck": "compute" if compute_s >= memory_s else "memory",

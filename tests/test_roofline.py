@@ -138,30 +138,27 @@ def test_pairwise_launch_model_flop_split():
     """The unit split is the point: sign-split moves l1dist work from the
     VPU bucket to the MXU bucket; the VPU loop has zero MXU stat FLOPs."""
     from repro.kernels.pairwise import specs as pw_specs
+    from repro.kernels.pairwise.kernel import launch_work
     nr = nc = 256
     d, m, B = 8, 16, 7
     lap = pw_specs.suggested_spec("laplacian", d)
-    mxu_form = rl.pairwise_launch_model(lap, nr, nc, d, m,
-                                        l1_route="mxu_signsplit", segments=B)
-    vpu_form = rl.pairwise_launch_model(lap, nr, nc, d, m,
-                                        l1_route="vpu_loop")
+    mxu_form = launch_work(lap, nr, nc, d, m, l1_route="mxu_signsplit",
+                           segments=B)
+    vpu_form = launch_work(lap, nr, nc, d, m, l1_route="vpu_loop")
     entries = nr * nc
     inner = 2 * d * B
-    assert mxu_form["mxu_gflops"] * 1e9 == pytest.approx(
-        (4 * inner + 2 * m) * entries)
-    assert vpu_form["vpu_gflops"] * 1e9 == pytest.approx(
-        (4 * d + 8) * entries)
-    assert vpu_form["mxu_gflops"] * 1e9 == pytest.approx(2 * m * entries)
+    assert mxu_form["mxu_flops"] == (4 * inner + 2 * m) * entries
+    assert vpu_form["vpu_flops"] == (4 * d + 8) * entries
+    assert vpu_form["mxu_flops"] == 2 * m * entries
     # dot: pure MXU statistic
     lin = pw_specs.suggested_spec("linear", d)
-    lin_model = rl.pairwise_launch_model(lin, nr, nc, d, m)
-    assert lin_model["mxu_gflops"] * 1e9 == pytest.approx(
-        (2 * d + 2 * m) * entries)
+    lin_model = launch_work(lin, nr, nc, d, m)
+    assert lin_model["mxu_flops"] == (2 * d + 2 * m) * entries
     # bf16 tiles halve the point bytes on the HBM floor
     rbf = pw_specs.suggested_spec("rbf", d)
-    f32b = rl.pairwise_launch_model(rbf, nr, nc, d, m)["hbm_gbytes"]
-    bf16b = rl.pairwise_launch_model(
-        rbf.with_precision("bf16_f32acc"), nr, nc, d, m)["hbm_gbytes"]
+    f32b = launch_work(rbf, nr, nc, d, m)["hbm_bytes"]
+    bf16b = launch_work(rbf.with_precision("bf16_f32acc"), nr, nc, d,
+                        m)["hbm_bytes"]
     assert bf16b < f32b
 
 
