@@ -97,9 +97,12 @@ class SPSDOperator:
         """True when ``fused_rows`` answers matmul-shaped plan bundles."""
         return False
 
-    def fused_rows(self, row_idx: Optional[jnp.ndarray], Vs):
+    def fused_rows(self, row_idx: Optional[jnp.ndarray], Vs,
+                   col_idx: Optional[jnp.ndarray] = None):
         """[K[row_idx, :] @ V for V in Vs] in one fused launch (row_idx=None
-        -> all rows).  Only called when ``supports_fused_matmat()``."""
+        -> all rows), preceded by the column gather K[row_idx, col_idx]
+        when ``col_idx`` is given.  Only called when
+        ``supports_fused_matmat()``."""
         raise NotImplementedError
 
     def supports_prefetch_slab(self) -> bool:
@@ -107,9 +110,11 @@ class SPSDOperator:
         scalar-prefetch launch (no gathered row copy)."""
         return False
 
-    def fused_slab(self, start_row, slab_len: int, Vs):
+    def fused_slab(self, start_row, slab_len: int, Vs,
+                   col_idx: Optional[jnp.ndarray] = None):
         """[K[start:start+slab_len, :] @ V for V in Vs] with the slab
-        addressed inside the launch (``start_row`` may be traced).  Rows at
+        addressed inside the launch (``start_row`` may be traced), preceded
+        by the slab's column gather when ``col_idx`` is given.  Rows at
         indices ≥ n are clamp duplicates the caller must mask.  Only called
         when ``supports_prefetch_slab()``."""
         raise NotImplementedError
@@ -351,28 +356,34 @@ class PairwiseKernel(SPSDOperator):
     def supports_fused_matmat(self) -> bool:
         return bool(self.use_pallas)
 
-    def fused_rows(self, row_idx, Vs):
+    def _landmarks(self, col_idx):
+        return None if col_idx is None else jnp.take(self.X, col_idx, axis=0)
+
+    def fused_rows(self, row_idx, Vs, col_idx=None):
         """One rectangular multi-RHS Pallas launch for a contiguous row slab:
         the slab's kernel tiles are computed once in VMEM and contracted
         against every right-hand side (``row_idx=None`` -> the square
-        all-rows launch)."""
+        all-rows launch).  With ``col_idx`` the launch first returns the
+        slab's columns K[row_idx, col_idx], computed from the landmark
+        points X[col_idx] as ``columns`` computes them."""
         from repro.kernels.pairwise import ops as pw_ops
         Xr = self.X if row_idx is None else jnp.take(self.X, row_idx, axis=0)
-        return pw_ops.kernel_matmat_multi_rows(self.spec, Xr, self.X, Vs,
-                                               edges=self.l1_edges())
+        return pw_ops.kernel_matmat_multi_rows(
+            self.spec, Xr, self.X, Vs, edges=self.l1_edges(),
+            Xl=self._landmarks(col_idx))
 
     def supports_prefetch_slab(self) -> bool:
         return bool(self.use_pallas)
 
-    def fused_slab(self, start_row, slab_len, Vs):
+    def fused_slab(self, start_row, slab_len, Vs, col_idx=None):
         """The scalar-prefetch slab launch: the shard's contiguous row range
         is addressed inside the kernel via a prefetched row-block offset
         (``ops.kernel_matmat_multi_slab``), so no per-device row-slice copy
-        of X is ever gathered."""
+        of X is ever gathered; ``col_idx`` as in ``fused_rows``."""
         from repro.kernels.pairwise import ops as pw_ops
         return pw_ops.kernel_matmat_multi_slab(
             self.spec, self.X, start_row, int(slab_len), Vs,
-            edges=self.l1_edges())
+            edges=self.l1_edges(), Xl=self._landmarks(col_idx))
 
     def cross(self, Xq, Vs):
         """[K(Xq, X) @ V for V in Vs] — the serving-path query launch.

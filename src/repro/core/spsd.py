@@ -223,8 +223,10 @@ def fast_model(
     and streams through the operator protocol (``repro.core.selection``).
     With a projection ``s_sketch`` on a streaming operator, the C gather and
     the K @ S product ride the SAME panel sweep — every kernel row panel is
-    evaluated exactly once for the whole model (PR-1 paid one extra n×c
-    evaluation plus a separate sweep).  ``mesh`` shards every sweep the model
+    evaluated exactly once for the whole model.  On the fused Pallas route
+    that sweep is one launch, which computes C's n·c entries from the
+    selected points beside the K @ S contraction (C takes no right-hand
+    side and no extra pass over X).  ``mesh`` shards every sweep the model
     AND the selection policy run; ``n_valid`` handles padded (ragged-batch)
     operators — the mask restricts the policy to valid rows too.
     """
@@ -280,9 +282,11 @@ def fast_model_with_error(
 
     The error probes Z are independent of the model, so K @ Z joins the same
     sweep that gathers C and applies the projection sketch: the whole
-    model-plus-evaluation pipeline reads each kernel row panel exactly once
-    (PR 1 used one sweep for the model and another for the error — plus two
-    more per adaptive round).  ``selection`` picks the policy that chooses
+    model-plus-evaluation pipeline reads each kernel row panel exactly once.
+    On the fused Pallas route (both sketch branches) the sweep is one
+    launch: it contracts each kernel tile against the probes (and the
+    projection sketch) and computes C's n·c entries from the landmark
+    points X[idx] in the same pass.  ``selection`` picks the policy that chooses
     C's columns (its declared sweeps are the only addition to the budget).
     Returns ``(approx, relative_error)`` with the same estimator as
     ``relative_error(method="hutchinson")``.  Its phases run inside the

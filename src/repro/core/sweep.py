@@ -127,7 +127,8 @@ class MatmulPlan:
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class ColumnGatherPlan:
-    """A[:, col_idx] — the C = K P gather, free once the panel exists."""
+    """A[:, col_idx] — the C = K P gather, free once the panel exists (the
+    fused route computes it in its launch from the selected points)."""
 
     col_idx: jnp.ndarray
 
@@ -361,9 +362,11 @@ class RowQuadFormPlan:
 #     supports_fused_matmat() -> bool
 #         True when the operator can answer a whole matmul-shaped plan bundle
 #         with one fused launch (e.g. a Pallas-backed ``PairwiseKernel``).
-#     fused_rows(row_idx, Vs) -> tuple[jnp.ndarray, ...]
+#     fused_rows(row_idx, Vs, col_idx=None) -> tuple[jnp.ndarray, ...]
 #         [A[row_idx, :] @ V for V in Vs] for a contiguous row slab
-#         (``row_idx=None`` means all rows — the square single-device case).
+#         (``row_idx=None`` means all rows — the square single-device case),
+#         preceded by the column gather A[row_idx, col_idx] when ``col_idx``
+#         is given.
 #
 # Every sweep consumer (``fast_model``, ``fast_cur``, eig/error metrics,
 # adaptive sampling) goes through ``sweep_operator`` and therefore gets the
@@ -373,23 +376,43 @@ class RowQuadFormPlan:
 # (``CountingOperator.last_route``).
 
 def is_matmul_shaped(plans: Sequence) -> bool:
-    """True when every plan reduces to A @ V for some dense right-hand side
-    (matmats as-is; column gathers as one-hot columns)."""
+    """True when every plan is answered by one fused launch: A @ V for a
+    dense right-hand side (matmats), or A[:, idx] (column gathers, computed
+    in the launch from the selected points, not as products)."""
     plans = list(plans)
     return bool(plans) and all(
         isinstance(p, (MatmulPlan, ColumnGatherPlan)) for p in plans)
 
 
-def fused_right_hand_sides(plans: Sequence, ncols: int):
-    """Dense f32 right-hand sides for a matmul-shaped plan bundle.
+def fused_right_hand_sides(plans: Sequence):
+    """A matmul-shaped bundle's operands for one fused launch: the dense f32
+    right-hand sides of its matmats, in plan order, and the column indices
+    of its gathers, concatenated in plan order (None without a gather).
+    The launch computes the gathered columns from the selected points, so
+    they cost n·c kernel entries and no right-hand side."""
+    Vs = tuple(p.V.astype(jnp.float32) for p in plans
+               if isinstance(p, MatmulPlan))
+    gathers = [p.col_idx for p in plans if isinstance(p, ColumnGatherPlan)]
+    col_idx = jnp.concatenate(gathers) if gathers else None
+    return Vs, col_idx
 
-    Column gathers ride along as one-hot right-hand sides (exact: each
-    output entry is one A entry times 1.0).
-    """
-    return tuple(
-        p.V.astype(jnp.float32) if isinstance(p, MatmulPlan)
-        else jax.nn.one_hot(p.col_idx, ncols, dtype=jnp.float32).T
-        for p in plans)
+
+def _in_plan_order(plans: Sequence, outs):
+    """A fused launch's outputs (the gathered columns first when there is a
+    gather, then one product per matmat) back in plan order."""
+    outs = list(outs)
+    C = (outs.pop(0) if any(isinstance(p, ColumnGatherPlan) for p in plans)
+         else None)
+    products = iter(outs)
+    res, off = [], 0
+    for p in plans:
+        if isinstance(p, ColumnGatherPlan):
+            c = p.col_idx.shape[0]
+            res.append(C[:, off:off + c])
+            off += c
+        else:
+            res.append(next(products))
+    return res
 
 
 def sweep_operator(op, plans: Sequence, block_size: Optional[int] = None,
@@ -401,8 +424,10 @@ def sweep_operator(op, plans: Sequence, block_size: Optional[int] = None,
     ('pallas_fused'), or — on a non-trivial mesh — a per-shard claim through
     the engine's ``slab_fn`` hook, where each device runs one rectangular
     row-slab launch and the partial carries are psum-reduced exactly like the
-    panel route ('pallas_fused_sharded').  Everything else walks the blocked
-    panel scan over ``op.block`` ('panel').  Each route runs inside a
+    panel route ('pallas_fused_sharded').  Column gathers ride the same
+    launch, computed from the selected points (a bundle of gathers alone on
+    a trivial mesh is one ``op.columns`` call).  Everything else walks the
+    blocked panel scan over ``op.block`` ('panel').  Each route runs inside a
     ``span`` named ``sweep.<route>``.
     """
     from repro.core.instrument import span   # instrument imports this module
@@ -416,13 +441,16 @@ def sweep_operator(op, plans: Sequence, block_size: Optional[int] = None,
     prec = getattr(op, "precision", "f32")
     suffix = "" if prec == "f32" else "+" + prec
     op._last_slab_mode = None          # only sharded fused claims set this
+    if fused:
+        Vs, col_idx = fused_right_hand_sides(plans)
     if fused and mesh_data_size(mesh) <= 1:
         op._last_sweep_route = "pallas_fused" + suffix
         with span("sweep.pallas_fused"):
-            return list(op.fused_rows(None, fused_right_hand_sides(plans, n)))
+            outs = ((op.columns(col_idx),) if not Vs
+                    else op.fused_rows(None, Vs, col_idx))
+            return _in_plan_order(plans, outs)
     if fused:
         op._last_sweep_route = "pallas_fused_sharded" + suffix
-        Vs = fused_right_hand_sides(plans, n)
         use_slab = op.supports_prefetch_slab()
         op._last_slab_mode = "prefetch" if use_slab else "gather"
 
@@ -434,12 +462,14 @@ def sweep_operator(op, plans: Sequence, block_size: Optional[int] = None,
             # all-sentinel shards, whose contributions ``valid`` zeroes);
             # the gather claim materializes the row slice.
             if use_slab:
-                outs = op.fused_slab(row_idx[0], row_idx.shape[0], Vs)
+                outs = op.fused_slab(row_idx[0], row_idx.shape[0], Vs,
+                                     col_idx)
             else:
-                outs = op.fused_rows(row_idx, Vs)
+                outs = op.fused_rows(row_idx, Vs, col_idx)
             v = valid.astype(jnp.float32)[:, None]
-            return tuple(p.init(n, n).at[row_idx].add(o * v)
-                         for p, o in zip(plans, outs))
+            return tuple(
+                p.init(n, n).at[row_idx].add(o * v) for p, o in
+                zip(plans, _in_plan_order(plans, outs)))
 
         # panel_fn=None: the claim is unconditional, the scan never runs
         with span("sweep.pallas_fused_sharded"):

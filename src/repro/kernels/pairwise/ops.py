@@ -35,9 +35,9 @@ def _interpret_mode() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _pad_rows(X: jnp.ndarray, mult: int) -> jnp.ndarray:
+def _pad_rows(X: jnp.ndarray, mult: int, at_least: int = 0) -> jnp.ndarray:
     n = X.shape[0]
-    pad = (-n) % mult
+    pad = max((-n) % mult, at_least - n)
     if pad == 0:
         return X
     return jnp.pad(X, ((0, pad), (0, 0)))
@@ -81,34 +81,62 @@ def kernel_block(spec: KernelSpec, Xr: jnp.ndarray, Xc: jnp.ndarray,
     return _kernel_block_jit(Xr, Xc, edges, spec, use_pallas, interpret)
 
 
+def _landmark_rows(Xl):
+    """Landmark points padded to whole tiles (None stays None), and their
+    row count: the column grid must take at least that many rows."""
+    if Xl is None:
+        return None, 0
+    Xlp = _pad_rows(Xl, _k.BLOCK_C)
+    return Xlp, Xlp.shape[0]
+
+
+def _dense_products(spec: KernelSpec, Xr, Xc, Vs, edges, Xl):
+    """The fused launch's outputs by the dense evaluation: C = K(Xr, Xl)
+    first when landmarks are given, then [K(Xr, Xc) @ V for V in Vs]."""
+    K = _specs.apply(spec, Xr, Xc, edges)
+    dt = spec.tile_dtype()
+    outs = tuple(
+        jax.lax.dot_general(K.astype(dt), V.astype(dt),
+                            dimension_numbers=(((1,), (0,)), ((), ())),
+                            precision=_specs.f32_precision(dt),
+                            preferred_element_type=jnp.float32)
+        for V in Vs)
+    if Xl is None:
+        return outs
+    return (_specs.apply(spec, Xr, Xl, edges),) + outs
+
+
+def _widths(Vs, Xl):
+    """The unpadded widths of a fused launch's outputs: C's (with
+    landmarks) first, then each right-hand side's."""
+    return ((Xl.shape[0],) if Xl is not None else ()) + tuple(
+        V.shape[1] for V in Vs)
+
+
 @partial(jax.jit, static_argnames=("spec", "use_pallas", "interpret"))
 def _kernel_matmat_multi_rows_jit(Xr: jnp.ndarray, Xc: jnp.ndarray, Vs,
-                                  edges, spec: KernelSpec, use_pallas: bool,
-                                  interpret: bool):
+                                  edges, Xl, spec: KernelSpec,
+                                  use_pallas: bool, interpret: bool):
     Vs = tuple(Vs)
     if not use_pallas:
-        K = _specs.apply(spec, Xr, Xc, edges)
-        dt = spec.tile_dtype()
-        return tuple(
-            jax.lax.dot_general(K.astype(dt), V.astype(dt),
-                                dimension_numbers=(((1,), (0,)), ((), ())),
-                                precision=_specs.f32_precision(dt),
-                                preferred_element_type=jnp.float32)
-            for V in Vs)
-    nr = Xr.shape[0]
-    ms = [V.shape[1] for V in Vs]
+        return _dense_products(spec, Xr, Xc, Vs, edges, Xl)
+    Xlp, nl_rows = _landmark_rows(Xl)
     Xrp = _pad_rows(Xr, _k.BLOCK_R)
-    Xcp = _pad_rows(Xc, _k.BLOCK_C)
-    Vps = tuple(_pad_cols(_pad_rows(V, _k.BLOCK_C), 128) for V in Vs)
+    Xcp = _pad_rows(Xc, _k.BLOCK_C, nl_rows)
+    Vps = tuple(_pad_cols(_pad_rows(V, _k.BLOCK_C, nl_rows), 128)
+                for V in Vs)
     outs = _k.pairwise_matmat_multi_padded(spec, Xrp, Xcp, Vps,
-                                           interpret=interpret, edges=edges)
-    return tuple(out[:nr, :m] for out, m in zip(outs, ms))
+                                           interpret=interpret, edges=edges,
+                                           Xl=Xlp)
+    nr = Xr.shape[0]
+    return tuple(out[:nr, :m] for out, m in zip(outs, _widths(Vs, Xl)))
 
 
 def kernel_matmat_multi_rows(spec: KernelSpec, Xr: jnp.ndarray,
                              Xc: jnp.ndarray, Vs, use_pallas: bool = True,
                              interpret: bool | None = None,
-                             edges: jnp.ndarray | None = None):
+                             edges: jnp.ndarray | None = None,
+                             Xl: jnp.ndarray | None = None):
     """[K(Xr, Xc) @ V for V in Vs] — the rectangular row-slab fusion.
 
     The gather-based fast path of the sweep engine: the caller materializes
@@ -117,16 +145,19 @@ def kernel_matmat_multi_rows(spec: KernelSpec, Xr: jnp.ndarray,
     in VMEM — and contracted against every right-hand side.  Prefer
     ``kernel_matmat_multi_slab`` when the slab is a contiguous range of
     ``Xc`` — it addresses the slab in-launch instead of copying it.
+
+    ``Xl`` (optional landmark points): the same launch also returns the
+    column gather C = K(Xr, Xl), first, computed from the landmark tiles.
     """
     if interpret is None:
         interpret = _interpret_mode()
-    return _kernel_matmat_multi_rows_jit(Xr, Xc, tuple(Vs), edges, spec,
+    return _kernel_matmat_multi_rows_jit(Xr, Xc, tuple(Vs), edges, Xl, spec,
                                          use_pallas, interpret)
 
 
 @partial(jax.jit,
          static_argnames=("spec", "slab_len", "use_pallas", "interpret"))
-def _kernel_matmat_multi_slab_jit(X: jnp.ndarray, start_row, Vs, edges,
+def _kernel_matmat_multi_slab_jit(X: jnp.ndarray, start_row, Vs, edges, Xl,
                                   spec: KernelSpec, slab_len: int,
                                   use_pallas: bool, interpret: bool):
     Vs = tuple(Vs)
@@ -137,17 +168,11 @@ def _kernel_matmat_multi_slab_jit(X: jnp.ndarray, start_row, Vs, edges,
         # the last row and are discarded by the caller's validity mask
         row_idx = jnp.clip(start + jnp.arange(slab_len), 0, n - 1)
         Xr = jnp.take(X, row_idx, axis=0)
-        K = _specs.apply(spec, Xr, X, edges)
-        dt = spec.tile_dtype()
-        return tuple(
-            jax.lax.dot_general(K.astype(dt), V.astype(dt),
-                                dimension_numbers=(((1,), (0,)), ((), ())),
-                                precision=_specs.f32_precision(dt),
-                                preferred_element_type=jnp.float32)
-            for V in Vs)
-    ms = [V.shape[1] for V in Vs]
-    Xp = _pad_rows(X, _k.BLOCK_R)
-    Vps = tuple(_pad_cols(_pad_rows(V, _k.BLOCK_C), 128) for V in Vs)
+        return _dense_products(spec, Xr, X, Vs, edges, Xl)
+    Xlp, nl_rows = _landmark_rows(Xl)
+    Xp = _pad_rows(X, _k.BLOCK_R, nl_rows)
+    Vps = tuple(_pad_cols(_pad_rows(V, _k.BLOCK_C, nl_rows), 128)
+                for V in Vs)
     # align the dynamic start down to a 128-row block boundary; the launch
     # covers [off·128, off·128 + nblocks·128) and the requested slab is cut
     # out afterwards (within ∈ [0, 128), so one extra block always suffices)
@@ -155,16 +180,18 @@ def _kernel_matmat_multi_slab_jit(X: jnp.ndarray, start_row, Vs, edges,
     within = start - off * _k.BLOCK_R
     nblocks = (slab_len + 2 * _k.BLOCK_R - 1) // _k.BLOCK_R
     outs = _k.pairwise_matmat_multi_slab(spec, Xp, off, nblocks, Vps,
-                                         interpret=interpret, edges=edges)
+                                         interpret=interpret, edges=edges,
+                                         Xl=Xlp)
     return tuple(
         jax.lax.dynamic_slice_in_dim(out, within, slab_len, axis=0)[:, :m]
-        for out, m in zip(outs, ms))
+        for out, m in zip(outs, _widths(Vs, Xl)))
 
 
 def kernel_matmat_multi_slab(spec: KernelSpec, X: jnp.ndarray, start_row,
                              slab_len: int, Vs, use_pallas: bool = True,
                              interpret: bool | None = None,
-                             edges: jnp.ndarray | None = None):
+                             edges: jnp.ndarray | None = None,
+                             Xl: jnp.ndarray | None = None):
     """[K(X[start:start+slab_len], X) @ V for V in Vs] without gathering.
 
     The scalar-prefetch slab launch: ``start_row`` may be a TRACED scalar —
@@ -172,11 +199,12 @@ def kernel_matmat_multi_slab(spec: KernelSpec, X: jnp.ndarray, start_row,
     one compiled launch serves every slab position of a shard_map sweep and
     no device ever materializes a row-slice copy of ``X``.  Rows at indices
     ≥ n (a tail slab) are duplicates of the last row/block; callers mask
-    them (the sweep engine's validity mask already does).
+    them (the sweep engine's validity mask already does).  ``Xl`` adds the
+    slab's rows of C = K(X, Xl) as the first output.
     """
     if interpret is None:
         interpret = _interpret_mode()
-    return _kernel_matmat_multi_slab_jit(X, start_row, tuple(Vs), edges,
+    return _kernel_matmat_multi_slab_jit(X, start_row, tuple(Vs), edges, Xl,
                                          spec, int(slab_len), use_pallas,
                                          interpret)
 
@@ -187,10 +215,10 @@ def kernel_matmat_multi(spec: KernelSpec, X: jnp.ndarray, Vs,
                         edges: jnp.ndarray | None = None):
     """[K(X, X) @ V for V in Vs] with each kernel tile computed ONCE.
 
-    The sweep-engine fast path: all right-hand sides (projection sketches,
-    Hutchinson probes, one-hot column gathers for C = K P) are contracted
-    against the same VMEM-resident kernel tile in a single Pallas launch.
-    The square special case of ``kernel_matmat_multi_rows``.
+    All right-hand sides (projection sketches, Hutchinson probes) are
+    contracted against the same VMEM-resident kernel tile in a single
+    Pallas launch.  The square special case of
+    ``kernel_matmat_multi_rows``.
     """
     return kernel_matmat_multi_rows(spec, X, X, Vs, use_pallas=use_pallas,
                                     interpret=interpret, edges=edges)

@@ -86,9 +86,10 @@ def test_span_names_ops_and_leaves_the_module_unchanged(fn):
 def test_certify_build_lowered_for_tpu_carries_records_and_scopes(
         monkeypatch):
     """A certify-shaped build (n = 2048, c = 64, s = 256, 16 probes, d = 18)
-    holds one sweep launch whose record counts 2·n²·(d + 128 + 128) MXU
-    FLOPs (the 64-column one-hot gather and the 16 probes each padded to
-    128), one S^T K S block launch, and ops under all five phase scopes."""
+    holds one sweep launch whose record counts 2·n²·(d + 128) MXU FLOPs for
+    K·Z (the 16 probes padded to 128) plus 2·n·128·d for C from the 64
+    landmark points (padded to 128), one S^T K S block launch, and ops under
+    all five phase scopes."""
     monkeypatch.setattr(pw_ops, "_interpret_mode", lambda: False)
     n, d, c, s, p = 2048, 18, 64, 256, 16
     spec = specs.rbf(4.27)
@@ -105,9 +106,11 @@ def test_certify_build_lowered_for_tpu_carries_records_and_scopes(
     launches = _launches(lowered)
     sweeps = [r for _, r in launches if r["kernel"] == "pairwise_matmat_multi"]
     assert len(sweeps) == 1
-    assert sweeps[0]["mxu_flops"] == str(2 * n * n * (d + 128 + 128))
-    assert sweeps[0]["entries"] == str(n * n)
+    assert sweeps[0]["mxu_flops"] == str(2 * n * n * (d + 128)
+                                         + 2 * n * 128 * d)
+    assert sweeps[0]["entries"] == str(n * (n + 128))
     assert sweeps[0]["precision"] == "f32"
+    assert sweeps[0]["landmarks"] == "128"
     blocks = [r for _, r in launches if r["kernel"] == "pairwise_block"]
     assert len(blocks) == 1
     m = 384                              # s + c = 320 sketch rows, padded
@@ -159,25 +162,56 @@ def test_every_pallas_launch_carries_its_name(name, fn):
     assert f'kernel_name = "{name}"' in lowered.as_text()
 
 
-@pytest.mark.parametrize("stat,nr,d,ms,segments,mxu_flops", [
-    # the sweep launch of susy-rbf.certify: n = 2^19, d = 18, the one-hot
-    # gather of 512 columns and 64 probes padded to 128
-    ("rbf", 2 ** 19, 18, (512, 128), 0, 2 * 2 ** 38 * 658),
+@pytest.mark.parametrize("stat,nr,d,ms,landmarks,segments,mxu_flops", [
+    # the sweep launch of susy-rbf.certify: n = 2^19, d = 18, 64 probes
+    # padded to 128, and C from 512 landmark points
+    ("rbf", 2 ** 19, 18, (128,), 512, 0,
+     2 * 2 ** 38 * 146 + 2 * 2 ** 19 * 512 * 18),
     # mnist-rbf.certify: n = 2^18, d = 784
-    ("rbf", 2 ** 18, 784, (512, 128), 0, 2 * 2 ** 36 * 1424),
+    ("rbf", 2 ** 18, 784, (128,), 512, 0,
+     2 * 2 ** 36 * 912 + 2 * 2 ** 18 * 512 * 784),
     # the sign-split l1 route: two contractions of inner width 2·d·B
-    ("laplacian", 256, 8, (128,), 7, 2 * 256 * 256 * (2 * 2 * 8 * 7 + 128)),
+    ("laplacian", 256, 8, (128,), 0, 7,
+     2 * 256 * 256 * (2 * 2 * 8 * 7 + 128)),
     # the VPU l1 loop issues only the right-hand-side contraction
-    ("laplacian", 256, 8, (128,), 0, 2 * 256 * 256 * 128),
+    ("laplacian", 256, 8, (128,), 0, 0, 2 * 256 * 256 * 128),
 ], ids=["susy", "mnist", "signsplit", "vpu_loop"])
-def test_launch_work_hand_values(stat, nr, d, ms, segments, mxu_flops):
+def test_launch_work_hand_values(stat, nr, d, ms, landmarks, segments,
+                                 mxu_flops):
     spec = specs.get_spec(stat)
     route = "mxu_signsplit" if segments else None
-    work = pw_kernel.launch_work(spec, nr, nr, d, sum(ms), route, segments)
+    work = pw_kernel.launch_work(spec, nr, nr, d, sum(ms), route, segments,
+                                 landmarks)
     assert work["mxu_flops"] == mxu_flops
-    assert work["entries"] == nr * nr
+    assert work["entries"] == nr * (nr + landmarks)
     edges = jnp.zeros((d, segments - 1)) if segments else None
-    rec = pw_kernel.launch_record("k", spec, nr, nr, d, ms, edges)
-    assert rec == {"kernel": "k", "mxu_flops": str(mxu_flops),
-                   "entries": str(nr * nr), "precision": "f32",
+    rec = pw_kernel.launch_record("k", spec, nr, nr, d, ms, edges, landmarks)
+    expected = {"kernel": "k", "mxu_flops": str(mxu_flops),
+                "entries": str(nr * (nr + landmarks)), "precision": "f32",
+                "passes": "not counted"}
+    if landmarks:
+        expected["landmarks"] = str(landmarks)
+    assert rec == expected
+
+
+@pytest.mark.parametrize("name,fn,nr,result,operands", [
+    ("pairwise_matmat_multi", _pw_multi, 256, "f32[256,128]{1,0}",
+     "f32[256,16]{1,0}, f32[256,16]{1,0}, f32[256,128]{1,0}"),
+    ("pairwise_matmat_slab", _pw_slab, 128, "f32[128,128]{1,0}",
+     "s32[1]{0}, f32[256,16]{1,0}, f32[256,16]{1,0}, f32[256,128]{1,0}"),
+])
+def test_launch_without_landmarks_is_unchanged(name, fn, nr, result,
+                                               operands):
+    """A fused launch given no landmark points (serving ``cross``, bundles
+    without a gather) keeps its record and its ``pallas_call`` signature:
+    the products alone out, no landmark operand, no ``landmarks`` field."""
+    lowered = _tpu_lowered(fn, jax.ShapeDtypeStruct((256, 16), jnp.float32),
+                           jax.ShapeDtypeStruct((256, 128), jnp.float32))
+    [(_, rec)] = _launches(lowered)
+    assert rec == {"kernel": name,
+                   "mxu_flops": str(2 * nr * 256 * (16 + 128)),
+                   "entries": str(nr * 256), "precision": "f32",
                    "passes": "not counted"}
+    sig = re.search(r"= (\S+) custom-call\(.*?operand_layout_constraints="
+                    r"\{(.*?)\}, frontend", lowered.as_text(dialect="hlo"))
+    assert sig.groups() == (result, operands)

@@ -57,7 +57,8 @@ def _edges(d, nseg):
 
 
 N = 2048             # rows: grid extent only, the tile body is what compiles
-M = (512, 128)       # right-hand-side widths: C's one-hot gather + probes
+M = (512, 128)       # right-hand-side widths: a sketch + probes
+C = 512              # landmark points: the certified build's column gather
 
 
 @pytest.mark.parametrize("precision", specs.PRECISIONS)
@@ -91,6 +92,34 @@ def test_prefetch_slab_launch(one_chip):
     off = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
     _compile(lambda X, off, *Vs: pw_kernel.pairwise_matmat_multi_slab(
         spec, X, off, N // 4 // pw_kernel.BLOCK_R, Vs), X, off, *Vs)
+
+
+@pytest.mark.parametrize("precision", specs.PRECISIONS)
+@pytest.mark.parametrize("kernel,d", [("rbf", 18), ("rbf", 784),
+                                      ("laplacian-signsplit", 16)])
+@pytest.mark.parametrize("launch", ["multi", "slab"])
+def test_landmark_sweep_launch(one_chip, launch, kernel, d, precision):
+    """The certified build's sweep: C from 512 landmark points and K·Z
+    against 128 probe columns, in one launch, at SUSY's and MNIST's
+    widths."""
+    if kernel == "rbf":
+        spec, edges = specs.rbf(4.0), None
+    else:
+        spec, edges = specs.laplacian(1.0 / d), _edges(d, 32)
+    spec = spec.with_precision(precision)
+    X = jax.ShapeDtypeStruct((N, d), jnp.float32, sharding=one_chip)
+    Xl = jax.ShapeDtypeStruct((C, d), jnp.float32, sharding=one_chip)
+    V = jax.ShapeDtypeStruct((N, 128), jnp.float32, sharding=one_chip)
+    off = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+    if launch == "multi":
+        text = _compile(lambda X, Xl, V: pw_kernel.pairwise_matmat_multi_padded(
+            spec, X, X, (V,), edges=edges, Xl=Xl), X, Xl, V)
+    else:
+        text = _compile(lambda X, Xl, off, V: pw_kernel.pairwise_matmat_multi_slab(
+            spec, X, off, N // 4 // pw_kernel.BLOCK_R, (V,), edges=edges,
+            Xl=Xl), X, Xl, off, V)
+    rows = N if launch == "multi" else N // 4
+    assert f"(f32[{rows},{C}]" in text          # C first, in the tuple
 
 
 @pytest.mark.parametrize("d", [16, 112, 784])
