@@ -43,5 +43,6 @@ for mode in ("landmark strided", "landmark adaptive"):
     agree = float(np.mean(outs["exact KV"] == outs[mode]))
     print(f"token agreement exact vs {mode}: {100 * agree:.1f}% "
           f"(c=48 landmarks over 96-token context)")
-print("landmark state per layer: O(c*(2d+1)) floats vs KV cache O(S*2d) — "
+print("landmark state per layer: O(c^2 + c*(2d+1)) floats vs KV cache "
+      "O(S*2d) — "
       "independent of context length")

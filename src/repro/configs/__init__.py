@@ -1,4 +1,4 @@
-"""Architecture registry: the 10 assigned archs x 4 input shapes (40 cells).
+"""Architecture registry: 11 archs x 4 input shapes.
 
 ``get_config(name)`` / ``get_smoke(name)`` return the exact published config
 (or its reduced smoke twin).  ``config_for_shape`` applies per-cell variants
@@ -31,6 +31,7 @@ _MODULES = {
     "chameleon-34b": "repro.configs.chameleon_34b",
     "whisper-large-v3": "repro.configs.whisper_large_v3",
     "recurrentgemma-2b": "repro.configs.recurrentgemma_2b",
+    "mellum2-12b-a2.5b": "repro.configs.mellum2_12b_a2_5b",
 }
 
 ARCHS: List[str] = list(_MODULES)

@@ -21,6 +21,20 @@ MIXER_KINDS = ("attn", "local", "global", "mlstm", "slstm", "rglru", "xattn")
 
 
 @dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN RoPE scaling (Peng et al. 2023) as the HF ``rope_type: yarn``
+    computes it: frequencies blend interpolated (÷ ``factor``) and original
+    ones over a wavelength ramp between ``beta_slow`` and ``beta_fast``
+    rotations in ``original_max_position`` positions, and cos/sin are scaled
+    by ``attention_factor``."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str                       # dense | moe | ssm | hybrid | vlm | audio
@@ -35,6 +49,7 @@ class ModelConfig:
     # --- attention ---
     rope_theta: float = 10_000.0
     rope_theta_local: float = 10_000.0
+    rope_yarn: Optional[YaRN] = None      # YaRN on the 'global' layers only
     qk_norm: bool = False
     window: Optional[int] = None          # sliding-window size for 'local'
     layer_pattern: Tuple[str, ...] = ("attn",)
@@ -53,10 +68,12 @@ class ModelConfig:
     n_shared_experts: int = 0
     moe_top_k: int = 0
     moe_d_ff: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25         # training dispatch only
     first_k_dense: int = 0
     dense_d_ff: int = 0
-    moe_impl: str = "gather"              # gather | shard_map
+    # training dispatch only: gather | shard_map (serving is dropless,
+    # ``moe.moe_ffn_served``, one path)
+    moe_impl: str = "gather"
 
     # --- MLA (deepseek) ---
     use_mla: bool = False

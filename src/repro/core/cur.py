@@ -106,8 +106,11 @@ def drineas08_U(A: jnp.ndarray, cidx: jnp.ndarray, ridx: jnp.ndarray) -> jnp.nda
 
 
 def fast_U_cur(ScC: jnp.ndarray, ScASr: jnp.ndarray, RSr: jnp.ndarray) -> jnp.ndarray:
-    """Ũ = (S_C^T C)† (S_C^T A S_R) (R S_R)†  (Eq. 9)."""
-    return pinv(ScC) @ ScASr.astype(jnp.float32) @ pinv(RSr)
+    """Ũ = (S_C^T C)† (S_C^T A S_R) (R S_R)†  (Eq. 9), products in f32 at
+    ``Precision.HIGHEST``."""
+    hi = jax.lax.Precision.HIGHEST
+    return jnp.matmul(jnp.matmul(pinv(ScC), ScASr.astype(jnp.float32),
+                                 precision=hi), pinv(RSr), precision=hi)
 
 
 def blocked_right_sketch(A, S, block_size: int = 1024,

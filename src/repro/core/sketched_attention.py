@@ -16,8 +16,11 @@ Nyströmformer is the degenerate S = P case (exactly the paper's reading of
 Nyström as a crude sketched solve); the prototype-quality solve is S = I.
 
 Cost: O(m·c + n·c + s²c) instead of O(m·n) — sub-quadratic for s = O(c√(n/ε)).
-For autoregressive decode with a fixed context the factors ``Ũ (R̂ V)`` and
-``Ũ (R̂ 1)`` are cached (c×d_v and c×1), making per-token cost O(c·d).
+For autoregressive decode the factors ``Ũ (R̂ V)`` and ``Ũ (R̂ 1)`` are
+cached (c×d_v and c×1) beside Ũ, making per-token cost O(c·d + c²): each
+decoded key and value is folded in as one more column of R̂ (the sketch and
+Ũ stay as built at prefill), and the query reads the updated factors — one
+Pallas launch, ``kernels/landmark_attention``.
 
 Landmark positions default to strided-with-jitter (``selection="strided"``),
 but any registered :class:`~repro.core.selection.SelectionPolicy` name picks
@@ -41,14 +44,23 @@ from repro.core.leverage import pinv
 class LandmarkState(NamedTuple):
     """Decode-time cache: everything that depends only on the context K/V."""
     k_land: jnp.ndarray    # (c, d)   landmark keys
+    U: jnp.ndarray         # (c, c)   the fast-CUR Ũ, to fold new columns
     UV: jnp.ndarray        # (c, d_v) Ũ @ (R̂ V)
     U1: jnp.ndarray        # (c,)     Ũ @ (R̂ 1)
     scale: jnp.ndarray     # ()       max-logit offset used inside exp
 
 
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _logits(Q: jnp.ndarray, K: jnp.ndarray) -> jnp.ndarray:
+    return jnp.matmul(Q.astype(jnp.float32), K.astype(jnp.float32).T,
+                      precision=HIGHEST)
+
+
 def _exp_scores(Q: jnp.ndarray, K: jnp.ndarray, inv_sqrt_d: float,
                 offset: jnp.ndarray) -> jnp.ndarray:
-    return jnp.exp((Q @ K.T).astype(jnp.float32) * inv_sqrt_d - offset)
+    return jnp.exp(_logits(Q, K) * inv_sqrt_d - offset)
 
 
 def signed_den_floor(den: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
@@ -191,7 +203,7 @@ def sketched_attention(
     Qp = jnp.take(Q, p_idx, axis=0) if m == n else jnp.take(K, p_idx, axis=0)
 
     # stabilization offset: max landmark logit (global max is close for RBF-ish G)
-    offset = jnp.max((Qp @ Kp.T).astype(jnp.float32)) * inv_sqrt_d
+    offset = jnp.max(_logits(Qp, Kp)) * inv_sqrt_d
 
     Chat = _exp_scores(Q, Kp, inv_sqrt_d, offset)       # (m, c)
     Rhat = _exp_scores(Qp, K, inv_sqrt_d, offset)       # (c, n)
@@ -210,8 +222,9 @@ def sketched_attention(
                             jnp.take(K, skx, axis=0), inv_sqrt_d, offset)
         U = fast_U_cur(ScC, G_blk, RSr)
 
-    num = Chat @ (U @ (Rhat @ V.astype(jnp.float32)))    # (m, d_v)
-    den = Chat @ (U @ jnp.sum(Rhat, axis=1))             # (m,)
+    mm = functools.partial(jnp.matmul, precision=HIGHEST)
+    num = mm(Chat, mm(U, mm(Rhat, V.astype(jnp.float32))))   # (m, d_v)
+    den = mm(Chat, mm(U, jnp.sum(Rhat, axis=1)))             # (m,)
     den = signed_den_floor(den)[:, None]
     return (num / den).astype(V.dtype)
 
@@ -223,36 +236,36 @@ def sketched_attention(
 def build_landmark_state(K: jnp.ndarray, V: jnp.ndarray, key: jax.Array,
                          c: int, theta: int = 4,
                          selection: str = "strided") -> LandmarkState:
-    """Precompute the context-side factors once (prefill)."""
+    """Precompute the context-side factors once (prefill), in f32."""
     n, d = K.shape
+    K = K.astype(jnp.float32)
     inv_sqrt_d = 1.0 / jnp.sqrt(d).astype(jnp.float32)
     kp, ks = jax.random.split(key)
     p_idx = select_landmarks(K, kp, c, selection=selection)
     c = p_idx.shape[0]            # may have been clamped to n
     Kp = jnp.take(K, p_idx, axis=0)
-    offset = jnp.max((Kp @ Kp.T).astype(jnp.float32)) * inv_sqrt_d
+    offset = jnp.max(_logits(Kp, Kp)) * inv_sqrt_d
 
     Rhat = _exp_scores(Kp, K, inv_sqrt_d, offset)        # (c, n)
     s = min(theta * c, n)
     skx = _extend_without_replacement(ks, p_idx, s, n)
     # queries at the sketched rows are the landmark keys themselves (self-Gram)
-    ScC = _exp_scores(jnp.take(K, skx, axis=0), Kp, inv_sqrt_d, offset)
-    G_blk = _exp_scores(jnp.take(K, skx, axis=0), jnp.take(K, skx, axis=0),
-                        inv_sqrt_d, offset)
+    Ks = jnp.take(K, skx, axis=0)
+    ScC = _exp_scores(Ks, Kp, inv_sqrt_d, offset)
+    G_blk = _exp_scores(Ks, Ks, inv_sqrt_d, offset)
     RSr = jnp.take(Rhat, skx, axis=1)
     U = fast_U_cur(ScC, G_blk, RSr)
 
-    RV = Rhat @ V.astype(jnp.float32)                    # (c, d_v)
+    mm = functools.partial(jnp.matmul, precision=HIGHEST)
+    RV = mm(Rhat, V.astype(jnp.float32))                 # (c, d_v)
     R1 = jnp.sum(Rhat, axis=1)                           # (c,)
-    return LandmarkState(k_land=Kp, UV=U @ RV, U1=U @ R1, scale=offset)
+    return LandmarkState(k_land=Kp, U=U, UV=mm(U, RV), U1=mm(U, R1),
+                         scale=offset)
 
 
 def landmark_decode(state: LandmarkState, q: jnp.ndarray) -> jnp.ndarray:
-    """One-token attention read: (d,) query -> (d_v,) output, O(c·d)."""
-    d = q.shape[-1]
-    inv_sqrt_d = 1.0 / jnp.sqrt(d).astype(jnp.float32)
-    logits = (state.k_land @ q.astype(jnp.float32)) * inv_sqrt_d - state.scale
-    cvec = jnp.exp(logits)                               # (c,)
-    num = cvec @ state.UV                                # (d_v,)
-    den = signed_den_floor(cvec @ state.U1)
-    return (num / den).astype(q.dtype)
+    """One-token attention read: (d,) query -> (d_v,) output, O(c·d), through
+    the landmark-attention launch."""
+    from repro.kernels.landmark_attention import ops as lm_ops
+    return lm_ops.landmark_read(q[None], state.k_land, state.UV, state.U1,
+                                state.scale)[0]

@@ -10,10 +10,11 @@ Decode caches (one per layer; stacked over scanned layers):
 - full / global : {"k": (B, Smax, KV, D), "v": ...}           (pos passed in)
 - local         : ring buffer {"k": (B, W, KV, D), "v": ...}
 - MLA           : {"ckv": (B, Smax, R), "krope": (B, Smax, Dr)} — the latent
-                  cache *is* a learned sketch of the KV Gram (DESIGN.md §5)
-- landmark      : the paper's fast-model factors per head:
-                  {"k_land": (B, KV, c, D), "uv": (B, KV, c, Dv),
-                   "u1": (B, KV, c), "offset": (B, KV)}
+                  cache *is* a learned sketch of the KV Gram
+- landmark      : the paper's fast-model factors per KV head, in f32:
+                  {"k_land": (B, KV, c, D), "u": (B, KV, c, c),
+                   "uv": (B, KV, c, Dv), "u1": (B, KV, c), "offset": (B, KV)}
+                  — every decoded key and value is folded into uv/u1
 """
 from __future__ import annotations
 
@@ -25,7 +26,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.core.sketched_attention import signed_den_floor
+from repro.core.instrument import span
 from repro.distributed import sharding as shd
 from repro.models import layers as L
 
@@ -80,16 +81,19 @@ def init_attention(key: jax.Array, cfg: ModelConfig,
 # ---------------------------------------------------------------------------
 
 def _qkv(params: dict, cfg: ModelConfig, x: jnp.ndarray,
-         positions: jnp.ndarray, theta: float):
+         positions: jnp.ndarray, kind: str):
     dt = cfg.cdtype
+    theta, yarn = L.rope_for(cfg, kind)
     q = jnp.einsum("bsd,dhk->bshk", x, params["wq"].astype(dt))
     k = jnp.einsum("bsd,dhk->bshk", x, params["wk"].astype(dt))
     v = jnp.einsum("bsd,dhk->bshk", x, params["wv"].astype(dt))
     if cfg.qk_norm:
         q = L.rmsnorm(params["q_norm"], q, cfg.norm_eps)
         k = L.rmsnorm(params["k_norm"], k, cfg.norm_eps)
-    q = L.apply_rope(q.swapaxes(1, 2), positions, theta).swapaxes(1, 2)
-    k = L.apply_rope(k.swapaxes(1, 2), positions, theta).swapaxes(1, 2)
+    q = L.apply_rope(q.swapaxes(1, 2), positions, theta,
+                     yarn).swapaxes(1, 2)
+    k = L.apply_rope(k.swapaxes(1, 2), positions, theta,
+                     yarn).swapaxes(1, 2)
     return q, k, v
 
 
@@ -98,6 +102,14 @@ NEG = -1e30
 # q-block scan (XLA-flash) keeps the transient at (B, H, bq, Sk)
 CHUNK_Q = 1024
 DENSE_LIMIT = 2048 * 2048
+
+
+def _weighted_values(spec: str, w: jnp.ndarray, v: jnp.ndarray):
+    """The softmax weights (f32) times the values, accumulated in f32: the
+    weights are rounded to the values' dtype first, the product a TPU makes
+    of an f32 operand at default precision, stated for every backend."""
+    return jnp.einsum(spec, w.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32)
 
 
 def _blk_attend(qb: jnp.ndarray, kb: jnp.ndarray, vb: jnp.ndarray,
@@ -117,7 +129,7 @@ def _blk_attend(qb: jnp.ndarray, kb: jnp.ndarray, vb: jnp.ndarray,
     if kv_valid is not None:                      # (B or 1, L) key validity
         logits = jnp.where(kv_valid[:, None, None, :], logits, NEG)
     w = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", w, vb.astype(jnp.float32))
+    return _weighted_values("bhqk,bkhd->bqhd", w, vb)
 
 
 def _gqa_decode_read(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -133,7 +145,7 @@ def _gqa_decode_read(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if kv_valid is not None:
         logits = jnp.where(kv_valid[:, None, None, :], logits, NEG)
     w = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkgs,bskd->bkgd", w, v.astype(jnp.float32))
+    out = _weighted_values("bkgs,bskd->bkgd", w, v)
     return out.reshape(B, 1, H, v.shape[-1]).astype(cfg.cdtype)
 
 
@@ -235,8 +247,7 @@ def attention_full(params: dict, cfg: ModelConfig, x: jnp.ndarray,
         # heads-misfit: shard query positions over 'model' so the q/k/v/o
         # projections and the score panels are TP-parallel in the sequence
         x = shd.constrain(x, P(None, "model", None))
-    theta = cfg.rope_theta_local if kind == "local" else cfg.rope_theta
-    q, k, v = _qkv(params, cfg, x, positions, theta)
+    q, k, v = _qkv(params, cfg, x, positions, kind)
     if sp:
         q = shd.constrain(q, P(None, "model", None, None))
         # keys/values: ONE explicit all-gather per layer (batch stays on the
@@ -334,11 +345,12 @@ def init_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int) -> dict:
         return {"k": jnp.zeros((batch, w, kv, hd), dt),
                 "v": jnp.zeros((batch, w, kv, hd), dt)}
     if kind == "global" and cfg.use_landmark_decode:
-        c = cfg.landmark_c
-        return {"k_land": jnp.zeros((batch, kv, c, hd), dt),
-                "uv": jnp.zeros((batch, kv, c, hd), dt),
-                "u1": jnp.zeros((batch, kv, c), jnp.float32),
-                "offset": jnp.zeros((batch, kv), jnp.float32)}
+        c, f32 = cfg.landmark_c, jnp.float32
+        return {"k_land": jnp.zeros((batch, kv, c, hd), f32),
+                "u": jnp.zeros((batch, kv, c, c), f32),
+                "uv": jnp.zeros((batch, kv, c, hd), f32),
+                "u1": jnp.zeros((batch, kv, c), f32),
+                "offset": jnp.zeros((batch, kv), f32)}
     return {"k": jnp.zeros((batch, max_len, kv, hd), dt),
             "v": jnp.zeros((batch, max_len, kv, hd), dt)}
 
@@ -360,12 +372,10 @@ def attention_decode(params: dict, cfg: ModelConfig, x: jnp.ndarray,
     """x: (B, 1, d). pos: scalar current position. Returns (y, new_cache)."""
     if cfg.use_mla and kind in ("attn", "global"):
         return _mla_decode(params, cfg, x, cache, pos)
-    if kind == "global" and cfg.use_landmark_decode and "k_land" in cache:
-        return _landmark_decode(params, cfg, x, cache, pos), cache
-
-    theta = cfg.rope_theta_local if kind == "local" else cfg.rope_theta
     positions = pos[None]
-    q, k_new, v_new = _qkv(params, cfg, x, positions, theta)
+    q, k_new, v_new = _qkv(params, cfg, x, positions, kind)
+    if kind == "global" and cfg.use_landmark_decode and "k_land" in cache:
+        return _landmark_decode(params, cfg, q, k_new, v_new, cache)
 
     if kind == "local" and cfg.window is not None:
         W = cache["k"].shape[1]
@@ -398,7 +408,7 @@ def _mla_decode(params: dict, cfg: ModelConfig, x: jnp.ndarray,
     """Absorbed MLA decode: attend in the latent (kv_lora) space.
 
     The latent cache ckv is exactly a *learned* c-dimensional sketch of the
-    K/V Gram — the architectural cousin of the paper's C = KP (DESIGN.md §5).
+    K/V Gram — the architectural cousin of the paper's C = KP.
     """
     dt = cfg.cdtype
     dn, dr, dv, R = (cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim,
@@ -452,52 +462,52 @@ def _mla_decode(params: dict, cfg: ModelConfig, x: jnp.ndarray,
     return y, {"ckv": ckv, "krope": krope}
 
 
-def _landmark_decode(params: dict, cfg: ModelConfig, x: jnp.ndarray,
-                     cache: dict, pos: jnp.ndarray) -> jnp.ndarray:
-    """One-token read against the paper's fast-model factors, O(c·d).
-
-    The new token is *not* folded into the landmark state (the state is a
-    context summary built at prefill; serve.py rebuilds it periodically —
-    the 'streaming refresh' policy, DESIGN.md §4.1).
-    """
-    dt = cfg.cdtype
-    q = jnp.einsum("bsd,dhk->bshk", x, params["wq"].astype(dt))
-    if cfg.qk_norm:
-        q = L.rmsnorm(params["q_norm"], q, cfg.norm_eps)
-    q = L.apply_rope(q.swapaxes(1, 2), pos[None], cfg.rope_theta)  # (B,H,1,D)
-    q = q[:, :, 0]                                           # (B,H,D)
+def _landmark_decode(params: dict, cfg: ModelConfig, q: jnp.ndarray,
+                     k_new: jnp.ndarray, v_new: jnp.ndarray,
+                     cache: dict) -> Tuple[jnp.ndarray, dict]:
+    """One decode step against the paper's fast-model factors: the new key
+    and value (B, 1, KV, D) are folded into every (session, KV head)'s
+    factors as one more column of R̂, and the group's queries read the
+    updated factors — one landmark-attention launch over B·KV factor sets,
+    O(c·D + c²) each, whatever the context length."""
+    from repro.kernels.landmark_attention import ops as lm_ops
+    B, _, H, D = q.shape
     KV = cfg.n_kv_heads
-    G = cfg.n_heads // KV
-    B, H, D = q.shape
-    qg = q.reshape(B, KV, G, D).astype(jnp.float32)
+    N = B * KV
+    c = cache["u1"].shape[-1]
+    out, (uv, u1, off) = lm_ops.landmark_step(
+        q.reshape(N, H // KV, D), cache["k_land"].reshape(N, c, D),
+        cache["u"].reshape(N, c, c), cache["uv"].reshape(N, c, -1),
+        cache["u1"].reshape(N, c), cache["offset"].reshape(N),
+        k_new.reshape(N, D), v_new.reshape(N, -1))
+    new_cache = dict(cache, uv=uv.reshape(cache["uv"].shape),
+                     u1=u1.reshape(B, KV, c), offset=off.reshape(B, KV))
+    out = out.reshape(B, 1, H, out.shape[-1]).astype(cfg.cdtype)
+    y = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(cfg.cdtype))
+    return y, new_cache
 
-    kl = cache["k_land"].astype(jnp.float32)                 # (B,KV,c,D)
-    logits = jnp.einsum("bkgd,bkcd->bkgc", qg, kl) / (D ** 0.5)
-    cvec = jnp.exp(logits - cache["offset"][:, :, None, None])
-    num = jnp.einsum("bkgc,bkcv->bkgv", cvec,
-                     cache["uv"].astype(jnp.float32))
-    den = jnp.einsum("bkgc,bkc->bkg", cvec, cache["u1"])
-    out = num / signed_den_floor(den)[..., None]
-    out = out.reshape(B, 1, H, out.shape[-1]).astype(dt)
-    return jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(dt))
 
-
-def build_landmark_cache(params: dict, cfg: ModelConfig, k: jnp.ndarray,
-                         v: jnp.ndarray, key: jax.Array) -> dict:
+@span("landmark.build")
+def build_landmark_cache(cfg: ModelConfig, k: jnp.ndarray, v: jnp.ndarray,
+                         key: jax.Array) -> dict:
     """Prefill-side construction of the landmark cache from full K/V
     (B, S, KV, D): the paper's Algorithm 1 applied to the softmax Gram,
-    batched over (B, KV)."""
+    batched over (B, KV), in f32.  Head j of session b draws its landmarks
+    and sketch from ``split(fold_in(key, b), KV)[j]``."""
     from repro.core.sketched_attention import build_landmark_state
 
     def one(kh, vh, kk):
         st = build_landmark_state(
             kh, vh, kk, c=cfg.landmark_c, theta=cfg.landmark_theta,
-            selection=getattr(cfg, "landmark_selection", "strided"))
-        return st.k_land, st.UV, st.U1, st.scale
+            selection=cfg.landmark_selection)
+        return st.k_land, st.U, st.UV, st.U1, st.scale
 
     B, S, KV, D = k.shape
-    keys = jax.random.split(key, B * KV).reshape(B, KV)
+    # session b's heads draw from fold_in(key, b): a session's landmarks do
+    # not depend on the batch it was prefilled in
+    keys = jax.vmap(lambda b: jax.random.split(jax.random.fold_in(key, b),
+                                               KV))(jnp.arange(B))
     kt = k.transpose(0, 2, 1, 3)                             # (B,KV,S,D)
     vt = v.transpose(0, 2, 1, 3)
-    k_land, uv, u1, off = jax.vmap(jax.vmap(one))(kt, vt, keys)
-    return {"k_land": k_land, "uv": uv, "u1": u1, "offset": off}
+    k_land, u, uv, u1, off = jax.vmap(jax.vmap(one))(kt, vt, keys)
+    return {"k_land": k_land, "u": u, "uv": uv, "u1": u1, "offset": off}

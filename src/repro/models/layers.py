@@ -6,6 +6,7 @@ take (params, x).  Sharding is attached *by name* via the rules in
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -79,12 +80,14 @@ def embed(params: dict, cfg: ModelConfig, tokens: jnp.ndarray) -> jnp.ndarray:
     return x
 
 
-def unembed(params: dict, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
+def unembed(params: dict, cfg: ModelConfig, x: jnp.ndarray,
+            out_dtype=None) -> jnp.ndarray:
+    """Logits in the compute dtype, or accumulated into ``out_dtype``."""
     if cfg.tie_embeddings:
         w = params["embedding"].astype(cfg.cdtype).T
     else:
         w = params["unembed"].astype(cfg.cdtype)
-    logits = x @ w
+    logits = jnp.matmul(x, w, preferred_element_type=out_dtype)
     if cfg.logits_softcap:
         c = cfg.logits_softcap
         logits = jnp.tanh(logits / c) * c
@@ -95,21 +98,54 @@ def unembed(params: dict, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
 # RoPE
 # ---------------------------------------------------------------------------
 
-def rope_frequencies(head_dim: int, theta: float) -> jnp.ndarray:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
-                            / head_dim))
+def rope_frequencies(head_dim: int, theta: float, yarn=None) -> jnp.ndarray:
+    """Inverse frequencies θ^(−2i/D); with ``yarn`` (``configs.base.YaRN``)
+    the HF ``rope_type: yarn`` blend: dimensions that turn fewer than
+    ``beta_slow`` times in ``original_max_position`` positions are
+    interpolated (÷ factor), those that turn more than ``beta_fast`` times
+    keep their frequency, and a linear ramp joins the two."""
+    inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                           / head_dim))
+    if yarn is None:
+        return inv
+
+    def corr_dim(rotations):
+        return (head_dim * math.log(yarn.original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp                      # 1: original, 0: interpolated
+    return inv / yarn.factor * (1.0 - keep) + inv * keep
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
-               theta: float) -> jnp.ndarray:
-    """x: (..., S, D) with positions (S,) or (..., S)."""
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               yarn=None) -> jnp.ndarray:
+    """x: (..., S, D) with positions (S,) or (..., S); rotate-half pairs
+    (i, i + D/2).  ``yarn`` scales the frequencies and cos/sin."""
     D = x.shape[-1]
-    freqs = rope_frequencies(D, theta)                       # (D/2,)
+    freqs = rope_frequencies(D, theta, yarn)                 # (D/2,)
     ang = positions[..., :, None].astype(jnp.float32) * freqs  # (..., S, D/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn is not None:
+        cos = cos * yarn.attention_factor
+        sin = sin * yarn.attention_factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def rope_for(cfg: ModelConfig, kind: str):
+    """(θ, YaRN or None) of a mixer kind: local layers take
+    ``rope_theta_local``; YaRN applies to ``global`` layers only."""
+    if kind == "local":
+        return cfg.rope_theta_local, None
+    return cfg.rope_theta, cfg.rope_yarn if kind == "global" else None
 
 
 # ---------------------------------------------------------------------------

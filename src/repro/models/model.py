@@ -1,24 +1,36 @@
 """Top-level models: decoder-only LM, early-fusion VLM, whisper enc-dec.
 
-``build_model(cfg)`` -> ``Model`` with five pure entry points the launcher
+``build_model(cfg)`` -> ``Model`` with the pure entry points the launcher
 jits/pjits:
 
   init(key)                               -> params
   loss(params, batch)                     -> (scalar, metrics)     train_step
   forward(params, batch)                  -> (logits, aux)
   prefill(params, batch, key, max_len)    -> (last_logits, cache)  serve
+    (``taps=True``: also the blocks' taps, ``transformer.stack_prefill``)
   decode_step(params, cache, tokens, pos) -> (logits, cache)       serve_step
+  decode_loop(params, cache, tokens, pos, keep, steps)             serve turn
+  generate(params, batch, key, keep, steps)   prefill + decode_loop
+
+``decode_loop`` runs ``steps`` greedy decode steps as one on-device program
+(a ``lax.fori_loop``): it feeds ``tokens`` (B,) at ``pos``, then each step's
+argmax, and returns ``Turn`` — the tokens fed (B, steps), the f32 logits of
+the sessions ``keep`` (K, steps, V), the cache after the last step, and each
+step's taps (``transformer.stack_decode``): the MoE's assignments per expert
+and delivered to the combine, of every session, and the routing and the
+landmark layers' queries, keys and values of the sessions ``keep``.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Callable, NamedTuple
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.core.instrument import span
 from repro.models import attention as A
 from repro.models import layers as L
 from repro.models import transformer as T
@@ -35,6 +47,19 @@ class Model(NamedTuple):
     prefill: Callable
     decode_step: Callable
     cache_shape: Callable        # (batch, max_len) -> zero cache pytree
+    decode_loop: Optional[Callable] = None
+    generate: Optional[Callable] = None
+
+
+class Turn(NamedTuple):
+    """What one ``decode_loop`` program returns."""
+    tokens: jnp.ndarray          # (B, steps) int32, the token fed at each step
+    logits: jnp.ndarray          # (K, steps, V) f32, of the kept sessions
+    cache: dict                  # after the last step
+    taps: dict                   # per step: "sizes" (steps, MoE layers, E),
+    # "delivered" (steps, MoE layers); of the kept sessions: "experts"
+    # (K, steps, MoE layers, k), "landmark_q" (K, steps, landmark layers,
+    # H, D), "landmark_k" / "landmark_v" (K, steps, landmark layers, KV, D)
 
 
 # ---------------------------------------------------------------------------
@@ -130,24 +155,83 @@ def _lm_loss(params: dict, cfg: ModelConfig, batch: dict):
     return total, metrics
 
 
+@span("serve.prefill")
 def _lm_prefill(params: dict, cfg: ModelConfig, batch: dict, key: jax.Array,
-                max_len: int):
+                max_len: int, taps: bool = False):
     x = _embed_inputs(params, cfg, batch)
     positions = jnp.arange(x.shape[1])
-    x, caches = T.stack_prefill(params["stack"], cfg, x, positions, max_len,
-                                key)
+    x, caches, tapped = T.stack_prefill(params["stack"], cfg, x, positions,
+                                        max_len, key)
     h_last = L.rmsnorm(params["final_norm"], x[:, -1:], cfg.norm_eps)
-    logits = L.unembed(params["embed"], cfg, h_last)
+    logits = L.unembed(params["embed"], cfg, h_last, jnp.float32)
+    if taps:
+        return logits[:, 0], caches, tapped
     return logits[:, 0], caches
+
+
+def _lm_step(params: dict, cfg: ModelConfig, cache: dict,
+             tokens: jnp.ndarray, pos: jnp.ndarray):
+    x = L.embed(params["embed"], cfg, tokens)                # (B, 1, d)
+    x, cache, taps = T.stack_decode(params["stack"], cfg, x, cache, pos)
+    h = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    logits = L.unembed(params["embed"], cfg, h, jnp.float32)
+    return logits[:, 0], cache, taps
 
 
 def _lm_decode(params: dict, cfg: ModelConfig, cache: dict,
                tokens: jnp.ndarray, pos: jnp.ndarray):
-    x = L.embed(params["embed"], cfg, tokens)                # (B, 1, d)
-    x, cache = T.stack_decode(params["stack"], cfg, x, cache, pos)
-    h = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = L.unembed(params["embed"], cfg, h)
-    return logits[:, 0], cache
+    logits, cache, _ = _lm_step(params, cfg, cache, tokens, pos)
+    return logits, cache
+
+
+@span("serve.decode")
+def _lm_decode_loop(params: dict, cfg: ModelConfig, cache: dict,
+                    tokens: jnp.ndarray, pos: jnp.ndarray,
+                    keep: jnp.ndarray, steps: int) -> Turn:
+    B, K = tokens.shape[0], keep.shape[0]
+
+    def kept_rows(name, a):
+        # taps with a batch axis (layers, B, 1, ...) keep the sessions
+        # ``keep``, sessions first; the others keep every session
+        if name in ("sizes", "delivered"):
+            return a
+        return jnp.take(a[:, :, 0], keep, axis=1).swapaxes(0, 1)
+
+    tap_shapes = jax.eval_shape(
+        lambda c, t: {n: kept_rows(n, a) for n, a in _lm_step(
+            params, cfg, c, t[:, None], pos)[2].items()}, cache, tokens)
+    taps0 = {n: jnp.zeros((steps,) + a.shape, a.dtype)
+             for n, a in tap_shapes.items()}
+
+    def body(i, carry):
+        tok, cache, fed, kept, taps = carry
+        logits, cache, t = _lm_step(params, cfg, cache, tok[:, None],
+                                    pos + i)
+        fed = fed.at[:, i].set(tok)
+        kept = kept.at[:, i].set(jnp.take(logits, keep, axis=0))
+        taps = {n: taps[n].at[i].set(kept_rows(n, a)) for n, a in t.items()}
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return nxt, cache, fed, kept, taps
+
+    carry = (tokens.astype(jnp.int32), cache,
+             jnp.zeros((B, steps), jnp.int32),
+             jnp.zeros((K, steps, cfg.vocab_size), jnp.float32), taps0)
+    _, cache, fed, kept, taps = jax.lax.fori_loop(0, steps, body, carry)
+    # steps first for every tap; the kept sessions' then lead
+    taps = {n: a if n in ("sizes", "delivered") else a.swapaxes(0, 1)
+            for n, a in taps.items()}
+    return Turn(fed, kept, cache, taps)
+
+
+def _lm_generate(params: dict, cfg: ModelConfig, batch: dict,
+                 key: jax.Array, keep: jnp.ndarray, steps: int) -> Turn:
+    """Prefill the prompts, then decode ``steps`` greedy tokens on the
+    device, the first from the prompt's last logits."""
+    S = batch["tokens"].shape[1]
+    logits, cache = _lm_prefill(params, cfg, batch, key, S + steps)
+    first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return _lm_decode_loop(params, cfg, cache, first,
+                           jnp.asarray(S, jnp.int32), keep, steps)
 
 
 # ---------------------------------------------------------------------------
@@ -261,8 +345,8 @@ def _encdec_prefill(params: dict, cfg: ModelConfig, batch: dict,
     def body(h, xs):
         sb, xp, kd = xs
         kk = jax.random.wrap_key_data(kd)
-        h, c = T.block_prefill(sb[0], dcfg, "attn", False, h, positions,
-                               max_len, kk)
+        h, c, _ = T.block_prefill(sb[0], dcfg, "attn", False, h,
+                                  positions, max_len, kk)
         xnorm = L.rmsnorm(xp["xnorm"], h, cfg.norm_eps)
         ek, ev = A.encoder_kv(xp["xattn"], cfg, enc_out)
         h = h + A.cross_attention(xp["xattn"], cfg, xnorm, ek, ev)
@@ -291,7 +375,7 @@ def _encdec_decode(params: dict, cfg: ModelConfig, cache: dict,
 
     def body(h, xs):
         sb, xp, c, ekv = xs
-        h, c2 = T.block_decode(sb[0], dcfg, "attn", False, h, c, pos)
+        h, c2, _ = T.block_decode(sb[0], dcfg, "attn", False, h, c, pos)
         xnorm = L.rmsnorm(xp["xnorm"], h, cfg.norm_eps)
         h = h + A.cross_attention(xp["xattn"], cfg, xnorm, ekv[0], ekv[1])
         return h, c2
@@ -334,8 +418,8 @@ def _encdec_cache(cfg: ModelConfig, batch: int, max_len: int,
 def _bind(fn, cfg):
     """Bind ``cfg`` into the second positional slot of ``fn(first, cfg, *rest)``."""
     @functools.wraps(fn)
-    def wrapped(first, *rest):
-        return fn(first, cfg, *rest)
+    def wrapped(first, *rest, **kw):
+        return fn(first, cfg, *rest, **kw)
     return wrapped
 
 
@@ -358,4 +442,6 @@ def build_model(cfg: ModelConfig) -> Model:
         prefill=_bind(_lm_prefill, cfg),
         decode_step=_bind(_lm_decode, cfg),
         cache_shape=functools.partial(T.stack_cache, cfg),
+        decode_loop=_bind(_lm_decode_loop, cfg),
+        generate=_bind(_lm_generate, cfg),
     )

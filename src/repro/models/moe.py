@@ -13,6 +13,17 @@ Dispatch is the static-shape sort-based gather path (TPU-native; no dense
 Shared experts (deepseek's 1, qwen's 4) are a plain dense MLP of width
 n_shared * moe_d_ff added unconditionally.
 
+Serving (prefill and decode, ``moe_ffn_served``) drops nothing: the T*k
+assignments are sorted by expert and each weight is one grouped product
+(``jax.lax.ragged_dot``) over the sorted rows, so every expert takes however
+many tokens were routed to it.  It also returns what it dispatched: the
+group sizes, the experts each token was routed to, and the rows the grouped
+products delivered to the combine (a caller counts the assignments dropped
+as those routed less those delivered).  Its router runs at
+``Precision.HIGHEST``: the router weights are f32, and a one-pass bf16
+product would move near-tied experts.  The served path has one
+implementation; ``moe_impl`` selects the training path only.
+
 ``shard_map`` variant (moe_impl='shard_map'): the same algorithm with the
 expert GEMMs under an explicit mesh-axis shard_map so the all-to-all is
 scheduled manually — used by the §Perf hillclimb.
@@ -43,9 +54,11 @@ def init_moe(key: jax.Array, cfg: ModelConfig) -> dict:
     return p
 
 
-def _route(params: dict, cfg: ModelConfig, xf: jnp.ndarray):
+def _route(params: dict, cfg: ModelConfig, xf: jnp.ndarray,
+           precision=None):
     """xf: (T, d) -> topk weights (T, k), indices (T, k), aux loss scalar."""
-    logits = xf.astype(jnp.float32) @ params["router"]       # (T, E)
+    logits = jnp.matmul(xf.astype(jnp.float32), params["router"],
+                        precision=precision)                 # (T, E)
     probs = jax.nn.softmax(logits, axis=-1)
     w, idx = jax.lax.top_k(probs, cfg.moe_top_k)
     w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-9)
@@ -97,6 +110,49 @@ def _dispatch_compute(params: dict, cfg: ModelConfig, xf: jnp.ndarray,
         sw * keep.astype(jnp.float32))[:, None].astype(dt)
     out = jnp.zeros((T, d), dt).at[st].add(vals)
     return out
+
+
+def _dropless_compute(params: dict, cfg: ModelConfig, xf: jnp.ndarray,
+                      w: jnp.ndarray, idx: jnp.ndarray):
+    """Grouped dispatch with no capacity. xf: (T, d) -> ((T, d), (E,) group
+    sizes, rows delivered: the assignments whose expert output reached the
+    combine)."""
+    T, d = xf.shape
+    E, k = cfg.n_experts, cfg.moe_top_k
+    dt = cfg.cdtype
+    eids = idx.reshape(-1)
+    order = jnp.argsort(eids, stable=True)
+    tok = order // k                                         # sorted rows
+    sizes = jnp.bincount(eids, length=E).astype(jnp.int32)
+    xs = jnp.take(xf, tok, axis=0).astype(dt)
+
+    def grouped(a, wname, out=jnp.float32):
+        return jax.lax.ragged_dot(a, params[wname].astype(dt), sizes,
+                                  preferred_element_type=out)
+
+    h = (jax.nn.silu(grouped(xs, "wi_gate"))
+         * grouped(xs, "wi_up")).astype(dt)
+    ob = grouped(h, "wo", dt)                                # (T*k, d)
+    delivered = jnp.sum(jnp.any(ob != 0, axis=-1), dtype=jnp.int32)
+    vals = ob.astype(jnp.float32) * w.reshape(-1)[order][:, None]
+    out = jnp.zeros((T, d), jnp.float32).at[tok].add(vals)
+    return out.astype(dt), sizes, delivered
+
+
+def moe_ffn_served(params: dict, cfg: ModelConfig, x: jnp.ndarray):
+    """Serving FFN, dropless. x: (B, S, d) -> (out, dispatch): ``dispatch``
+    holds ``sizes`` (E,) the assignments routed to each expert, ``experts``
+    (B, S, k) the experts each token was routed to, and ``delivered`` the
+    assignments whose expert output reached the combine."""
+    B, S, d = x.shape
+    xf = x.reshape(B * S, d)
+    w, idx, _ = _route(params, cfg, xf, jax.lax.Precision.HIGHEST)
+    out, sizes, delivered = _dropless_compute(params, cfg, xf, w, idx)
+    if cfg.n_shared_experts:
+        out = out + L.mlp(params["shared"], cfg, xf)
+    dispatch = {"sizes": sizes, "experts": idx.reshape(B, S, -1),
+                "delivered": delivered}
+    return out.reshape(B, S, d), dispatch
 
 
 def moe_ffn(params: dict, cfg: ModelConfig,

@@ -91,7 +91,7 @@ def _encoder_attention(params: dict, cfg: ModelConfig, x: jnp.ndarray,
     sp = A._sp_active(cfg, x.shape[1])
     if sp:
         x = shd.constrain(x, P(None, "model", None))
-    q, k, v = A._qkv(params, cfg, x, positions, cfg.rope_theta)
+    q, k, v = A._qkv(params, cfg, x, positions, "attn")
     out = A._sdpa(q, k, v, cfg, causal=False)
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(cfg.cdtype))
     if sp:
@@ -128,12 +128,17 @@ def block_full(params: dict, cfg: ModelConfig, kind: str, moe: bool,
 def block_prefill(params: dict, cfg: ModelConfig, kind: str, moe: bool,
                   x: jnp.ndarray, positions: jnp.ndarray, max_len: int,
                   key: jax.Array) -> Tuple[jnp.ndarray, dict]:
-    """Returns (x', cache_entry). Shares compute structure with block_full."""
+    """Returns (x', cache_entry, taps).  Shares compute structure with
+    block_full.  ``taps`` is what the block exposes of its work: a MoE
+    layer's ``experts`` (B, S, k), the experts each token was routed to; a
+    landmark layer's ``landmark_k`` and ``landmark_v`` (B, S, KV, D), the
+    keys and values its factors were built from."""
     B, S, _ = x.shape
     xin = L.rmsnorm(params["norm1"], x, cfg.norm_eps)
+    taps = {}
     if kind in ATTN_KINDS:
-        cache = _attn_prefill_cache(params["mixer"], cfg, kind, xin,
-                                    positions, max_len, key)
+        cache, taps = _attn_prefill_cache(params["mixer"], cfg, kind, xin,
+                                          positions, max_len, key)
         h = A.attention_full(params["mixer"], cfg, xin, positions, kind)
     elif kind == "mlstm":
         h = R.mlstm_full(params["mixer"], cfg, xin)
@@ -146,22 +151,33 @@ def block_prefill(params: dict, cfg: ModelConfig, kind: str, moe: bool,
         cache = _rec_prefill_state(params["mixer"], cfg, kind, xin)
     if cfg.post_norm:
         h = L.rmsnorm(params["post_norm1"], h, cfg.norm_eps)
-    x = x + h
+    x, dispatch = _served_ffn(params, cfg, x + h)
+    if dispatch is not None:
+        taps["experts"] = dispatch["experts"]
+    return x, cache, taps
+
+
+def _served_ffn(params: dict, cfg: ModelConfig, x: jnp.ndarray):
+    """The residual FFN half of a served block (prefill or decode): MoE
+    layers dispatch dropless.  Returns (x', the MoE's dispatch record
+    (``moe.moe_ffn_served``) or None)."""
+    dispatch = None
     if "mlp" in params or "moe" in params:
         h = L.rmsnorm(params["norm2"], x, cfg.norm_eps)
         if "moe" in params:
-            h, _ = M.moe_ffn(params["moe"], cfg, h)
+            h, dispatch = M.moe_ffn_served(params["moe"], cfg, h)
         else:
             h = L.mlp(params["mlp"], cfg, h)
         if cfg.post_norm:
             h = L.rmsnorm(params["post_norm2"], h, cfg.norm_eps)
         x = x + h
-    return x, cache
+    return x, dispatch
 
 
 def _attn_prefill_cache(mp: dict, cfg: ModelConfig, kind: str,
                         xin: jnp.ndarray, positions: jnp.ndarray,
-                        max_len: int, key: jax.Array) -> dict:
+                        max_len: int, key: jax.Array):
+    """(cache entry, taps: a landmark layer's keys and values)."""
     B, S, _ = xin.shape
     dt = cfg.cdtype
     if cfg.use_mla:
@@ -173,11 +189,11 @@ def _attn_prefill_cache(mp: dict, cfg: ModelConfig, kind: str,
             cfg.rope_theta).swapaxes(1, 2)[:, :, 0]
         pad = max_len - S
         return {"ckv": jnp.pad(ckv, ((0, 0), (0, pad), (0, 0))),
-                "krope": jnp.pad(krope, ((0, 0), (0, pad), (0, 0)))}
-    theta = cfg.rope_theta_local if kind == "local" else cfg.rope_theta
-    _, k, v = A._qkv(mp, cfg, xin, positions, theta)         # (B,S,KV,D)
+                "krope": jnp.pad(krope, ((0, 0), (0, pad), (0, 0)))}, {}
+    _, k, v = A._qkv(mp, cfg, xin, positions, kind)          # (B,S,KV,D)
     if kind == "global" and cfg.use_landmark_decode:
-        return A.build_landmark_cache(mp, cfg, k, v, key)
+        return A.build_landmark_cache(cfg, k, v, key), \
+            {"landmark_k": k, "landmark_v": v}
     if kind == "local" and cfg.window is not None:
         W = min(cfg.window, max_len)
         j = jnp.arange(W)
@@ -188,10 +204,10 @@ def _attn_prefill_cache(mp: dict, cfg: ModelConfig, kind: str,
         vw = jnp.take(v, src, axis=1)
         kr = jnp.zeros((B, W) + k.shape[2:], k.dtype).at[:, slots].set(kw)
         vr = jnp.zeros((B, W) + v.shape[2:], v.dtype).at[:, slots].set(vw)
-        return {"k": kr, "v": vr}
+        return {"k": kr, "v": vr}, {}
     pad = max_len - S
     return {"k": jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))),
-            "v": jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))}
+            "v": jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))}, {}
 
 
 def _rec_prefill_state(mp: dict, cfg: ModelConfig, kind: str,
@@ -222,8 +238,12 @@ def _rec_prefill_state(mp: dict, cfg: ModelConfig, kind: str,
 # --- decode ----------------------------------------------------------------
 
 def block_decode(params: dict, cfg: ModelConfig, kind: str, moe: bool,
-                 x: jnp.ndarray, cache: dict,
-                 pos: jnp.ndarray) -> Tuple[jnp.ndarray, dict]:
+                 x: jnp.ndarray, cache: dict, pos: jnp.ndarray):
+    """Returns (x', cache', taps): a MoE layer's dispatch record
+    (``moe.moe_ffn_served``: ``sizes``, ``experts`` (B, 1, k),
+    ``delivered``); a landmark layer's ``landmark_q`` (B, 1, H, D),
+    ``landmark_k`` and ``landmark_v`` (B, 1, KV, D), the queries that read
+    its factors and the key and value folded into them."""
     xin = L.rmsnorm(params["norm1"], x, cfg.norm_eps)
     if kind in ATTN_KINDS:
         h, cache = A.attention_decode(params["mixer"], cfg, xin, cache,
@@ -236,17 +256,12 @@ def block_decode(params: dict, cfg: ModelConfig, kind: str, moe: bool,
         h, cache = R.rglru_decode(params["mixer"], cfg, xin, cache)
     if cfg.post_norm:
         h = L.rmsnorm(params["post_norm1"], h, cfg.norm_eps)
-    x = x + h
-    if "mlp" in params or "moe" in params:
-        h = L.rmsnorm(params["norm2"], x, cfg.norm_eps)
-        if "moe" in params:
-            h, _ = M.moe_ffn(params["moe"], cfg, h)
-        else:
-            h = L.mlp(params["mlp"], cfg, h)
-        if cfg.post_norm:
-            h = L.rmsnorm(params["post_norm2"], h, cfg.norm_eps)
-        x = x + h
-    return x, cache
+    taps = {}
+    if kind == "global" and cfg.use_landmark_decode and "k_land" in cache:
+        q, k, v = A._qkv(params["mixer"], cfg, xin, pos[None], kind)
+        taps = {"landmark_q": q, "landmark_k": k, "landmark_v": v}
+    x, dispatch = _served_ffn(params, cfg, x + h)
+    return x, cache, dict(taps, **(dispatch or {}))
 
 
 def block_cache_shape(cfg: ModelConfig, kind: str, batch: int,
@@ -358,70 +373,103 @@ def stack_full(params: dict, cfg: ModelConfig, x: jnp.ndarray,
     return x, aux
 
 
+def _stack_taps(per_layer: list, lead: bool = False) -> dict:
+    """Per-layer taps in depth order -> one dict: each key's arrays stacked
+    over the layers that have it (``lead``: concatenated along an existing
+    leading layer axis)."""
+    keys = dict.fromkeys(k for t in per_layer for k in t)
+    join = jnp.concatenate if lead else jnp.stack
+    return {k: join([t[k] for t in per_layer if k in t]) for k in keys}
+
+
+def _scanned_taps(taps: dict) -> dict:
+    """(repeats, layers of a repeat, ...) -> (layers in depth order, ...)."""
+    return {k: a.reshape((-1,) + a.shape[2:]) for k, a in taps.items()}
+
+
+def _first(taps: dict) -> dict:
+    return {k: a[None] for k, a in taps.items()}
+
+
 def stack_prefill(params: dict, cfg: ModelConfig, x: jnp.ndarray,
                   positions: jnp.ndarray, max_len: int, key: jax.Array):
+    """Returns (x, caches, taps): each block's taps (``block_prefill``)
+    stacked over the layers that have them, in depth order."""
     prefix, pattern, reps, remainder = stack_layout(cfg)
     caches = {"prefix": [], "scanned": None, "remainder": []}
+    taps = []
     for i, (p, kind) in enumerate(zip(params["prefix"], prefix)):
-        x, c = block_prefill(p, cfg, kind, False, x, positions, max_len,
-                             jax.random.fold_in(key, 1000 + i))
+        x, c, t = block_prefill(p, cfg, kind, False, x, positions, max_len,
+                                jax.random.fold_in(key, 1000 + i))
         caches["prefix"].append(c)
+        taps.append(_first(t))
 
     def super_body(h, xs):
         sb_params, kd = xs
         kk = jax.random.wrap_key_data(kd)
-        cs = []
+        cs, ts = [], []
         for i, kind in enumerate(pattern):
-            h, c = block_prefill(sb_params[i], cfg, kind,
-                                 _is_moe_layer(cfg, False), h, positions,
-                                 max_len, jax.random.fold_in(kk, i))
+            h, c, t = block_prefill(sb_params[i], cfg, kind,
+                                    _is_moe_layer(cfg, False), h, positions,
+                                    max_len, jax.random.fold_in(kk, i))
             cs.append(c)
-        return h, tuple(cs)
+            ts.append(t)
+        return h, (tuple(cs), _stack_taps(ts))
 
     if reps > 0:
         keys = jax.random.key_data(jax.random.split(key, reps))
         if cfg.scan_layers:
-            x, sc = jax.lax.scan(super_body, x, (params["scanned"], keys))
+            x, (sc, st) = jax.lax.scan(super_body, x,
+                                       (params["scanned"], keys))
         else:
             sc_list = []
             for i, sb in enumerate(params["scanned"]):
                 x, c = super_body(x, (sb, keys[i]))
                 sc_list.append(c)
-            sc = jax.tree.map(lambda *xs: jnp.stack(xs), *sc_list)
+            sc, st = jax.tree.map(lambda *xs: jnp.stack(xs), *sc_list)
         caches["scanned"] = sc
+        taps.append(_scanned_taps(st))
 
     for i, (p, kind) in enumerate(zip(params["remainder"], remainder)):
-        x, c = block_prefill(p, cfg, kind, _is_moe_layer(cfg, False), x,
-                             positions, max_len,
-                             jax.random.fold_in(key, 2000 + i))
+        x, c, t = block_prefill(p, cfg, kind, _is_moe_layer(cfg, False), x,
+                                positions, max_len,
+                                jax.random.fold_in(key, 2000 + i))
         caches["remainder"].append(c)
+        taps.append(_first(t))
     caches["prefix"] = tuple(caches["prefix"])
     caches["remainder"] = tuple(caches["remainder"])
-    return x, caches
+    return x, caches, _stack_taps(taps, lead=True)
 
 
 def stack_decode(params: dict, cfg: ModelConfig, x: jnp.ndarray,
                  caches: dict, pos: jnp.ndarray):
+    """One decode step through the stack.  Returns (x, caches', taps): each
+    block's taps (``block_decode``) stacked over the layers that have them,
+    in depth order — for a MoE model ``sizes`` (MoE layers, E), ``experts``
+    (MoE layers, B, 1, k), ``delivered`` (MoE layers,)."""
     prefix, pattern, reps, remainder = stack_layout(cfg)
+    taps = []
     new_prefix = []
     for p, kind, c in zip(params["prefix"], prefix, caches["prefix"]):
-        x, c2 = block_decode(p, cfg, kind, False, x, c, pos)
+        x, c2, t = block_decode(p, cfg, kind, False, x, c, pos)
         new_prefix.append(c2)
+        taps.append(_first(t))
 
     def super_body(h, xs):
         sb_params, sb_cache = xs
-        cs = []
+        cs, ts = [], []
         for i, kind in enumerate(pattern):
-            h, c2 = block_decode(sb_params[i], cfg, kind,
-                                 _is_moe_layer(cfg, False), h, sb_cache[i],
-                                 pos)
+            h, c2, t = block_decode(sb_params[i], cfg, kind,
+                                    _is_moe_layer(cfg, False), h,
+                                    sb_cache[i], pos)
             cs.append(c2)
-        return h, tuple(cs)
+            ts.append(t)
+        return h, (tuple(cs), _stack_taps(ts))
 
     new_scanned = caches.get("scanned")
     if reps > 0:
         if cfg.scan_layers:
-            x, new_scanned = jax.lax.scan(
+            x, (new_scanned, st) = jax.lax.scan(
                 super_body, x, (params["scanned"], caches["scanned"]))
         else:
             outs = []
@@ -429,16 +477,19 @@ def stack_decode(params: dict, cfg: ModelConfig, x: jnp.ndarray,
                 sb_cache = jax.tree.map(lambda t: t[i], caches["scanned"])
                 x, c2 = super_body(x, (sb, sb_cache))
                 outs.append(c2)
-            new_scanned = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+            new_scanned, st = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+        taps.append(_scanned_taps(st))
 
     new_rem = []
     for p, kind, c in zip(params["remainder"], remainder,
                           caches["remainder"]):
-        x, c2 = block_decode(p, cfg, kind, _is_moe_layer(cfg, False), x, c,
-                             pos)
+        x, c2, t = block_decode(p, cfg, kind, _is_moe_layer(cfg, False), x,
+                                c, pos)
         new_rem.append(c2)
-    return x, {"prefix": tuple(new_prefix), "scanned": new_scanned,
-               "remainder": tuple(new_rem)}
+        taps.append(_first(t))
+    caches = {"prefix": tuple(new_prefix), "scanned": new_scanned,
+              "remainder": tuple(new_rem)}
+    return x, caches, _stack_taps(taps, lead=True)
 
 
 def stack_cache(cfg: ModelConfig, batch: int, max_len: int) -> dict:

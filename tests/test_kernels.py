@@ -86,6 +86,30 @@ def test_landmark_read_vs_ref(m, c, d, dv, dtype):
                                np.asarray(ref, np.float32), **_tol(dtype))
 
 
+@pytest.mark.parametrize("N,G,c,d", [(3, 8, 16, 32), (2, 4, 32, 64),
+                                     (1, 1, 8, 128)])
+def test_landmark_step_vs_ref(N, G, c, d):
+    """The fold-and-read launch over N factor sets against the per-set
+    oracle: factors updated in place, the offset raised by a large key."""
+    ks = jax.random.split(jax.random.PRNGKey(8), 8)
+    q = jax.random.normal(ks[0], (N, G, d)) * 0.5
+    kl = jax.random.normal(ks[1], (N, c, d)) * 0.5
+    U = jax.random.normal(ks[2], (N, c, c)) / c
+    UV = jax.random.normal(ks[3], (N, c, d))
+    U1 = jnp.abs(jax.random.normal(ks[4], (N, c))) + 0.5
+    off = jnp.full((N,), 0.3)
+    kn = jax.random.normal(ks[5], (N, d)) * jnp.array([0.5, 8.0, 0.5])[:N, None]
+    vn = jax.random.normal(ks[6], (N, d))
+    out, (uv, u1, o) = lm_ops.landmark_step(q, kl, U, UV, U1, off, kn, vn)
+    for i in range(N):
+        w_out, (w_uv, w_u1, w_o) = lm_ref.landmark_step(
+            q[i], kl[i], U[i], UV[i], U1[i], off[i], kn[i], vn[i])
+        for a, b in ((out[i], w_out), (uv[i], w_uv), (u1[i], w_u1),
+                     (o[i], w_o)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-5, atol=2e-5)
+
+
 # ---------------------------------------------------------------------------
 # fused RBF sketch blocks
 # ---------------------------------------------------------------------------

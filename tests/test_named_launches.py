@@ -122,6 +122,40 @@ def test_certify_build_lowered_for_tpu_carries_records_and_scopes(
         assert any(f"/{phase}/" in n for n in names), phase
 
 
+def test_decode_turn_lowered_for_tpu_carries_records_and_scopes(
+        monkeypatch):
+    """mellum2's CPU twin (3 sliding + 1 full layer), one decode turn of 4
+    steps for 3 sessions: the full layer's step is one landmark launch
+    whose record counts the fold and the read of 3 × 2 factor sets, and the
+    turn, the prefill and the landmark build run under their spans."""
+    from repro.configs import get_smoke
+    from repro.kernels.landmark_attention import ops as lm_ops
+    from repro.models.model import build_model
+    monkeypatch.setattr(lm_ops, "_interpret_mode", lambda: False)
+    cfg = get_smoke("mellum2-12b-a2.5b")
+    m = build_model(cfg)
+    params = jax.eval_shape(m.init, jax.random.PRNGKey(0))
+    B, P = 3, 16
+    tokens = jax.ShapeDtypeStruct((B, P), jnp.int32)
+    lowered = _tpu_lowered(
+        lambda p, t, k: m.generate(p, {"tokens": t}, k,
+                                   jnp.zeros((1,), jnp.int32), steps=4),
+        params, tokens, jax.random.key(0))
+    records = [r for _, r in _launches(lowered)
+               if r and r["kernel"] == "landmark_attention"]
+    assert len(records) == 1                 # in the loop body, once
+    n, g, c, d = B * cfg.n_kv_heads, 8, cfg.landmark_c, cfg.head_dim
+    work = lm_kernel.launch_work(n, g, c, d, d, fold=True)
+    assert records[0] == {"kernel": "landmark_attention",
+                          "flops": str(work["flops"]),
+                          "bytes": str(work["bytes"]),
+                          "inplace_bytes": str(work["inplace_bytes"]),
+                          "fold": "1"}
+    names = _op_names(lowered)
+    for scope in ("serve.prefill", "landmark.build", "serve.decode"):
+        assert any(f"{scope}/" in n for n in names), scope
+
+
 def _pw_multi(X, V):
     return pw_kernel.pairwise_matmat_multi_padded(specs.rbf(1.0), X, X, (V,))
 
@@ -143,8 +177,9 @@ def _flash(X, V):
 
 
 def _landmark(X, V):
-    return lm_kernel.landmark_read_padded(X, X[:128], V[:128, :16],
-                                          V[:128, 0], jnp.zeros(()))
+    return lm_kernel.landmark_attention_padded(
+        X[None], X[None, :128], V[None, :128, :16], V[None, :1, :128],
+        jnp.zeros((1, 1, 1)))
 
 
 @pytest.mark.parametrize("name,fn", [

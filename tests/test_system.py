@@ -25,10 +25,10 @@ def test_public_imports():
 
 def test_cell_matrix():
     from repro.configs import ARCHS, cells, shapes_for, LONG_CONTEXT_OK
-    assert len(ARCHS) == 10
+    assert len(ARCHS) == 11
     cs = list(cells())
-    # 10 archs x 4 shapes - 7 long_500k skips = 33 runnable cells
-    assert len(cs) == 33
+    # 11 archs x 4 shapes - 8 long_500k skips = 36 runnable cells
+    assert len(cs) == 36
     for a in ARCHS:
         names = [s.name for s in shapes_for(a)]
         assert "train_4k" in names and "prefill_32k" in names \
@@ -47,7 +47,8 @@ def test_param_counts_match_published():
               "qwen2-moe-a2.7b": (13e9, 15e9),
               "chameleon-34b": (32e9, 36e9),
               "whisper-large-v3": (1.4e9, 1.7e9),
-              "recurrentgemma-2b": (2.5e9, 3.2e9)}
+              "recurrentgemma-2b": (2.5e9, 3.2e9),
+              "mellum2-12b-a2.5b": (11.5e9, 12.5e9)}
     for a, (lo, hi) in expect.items():
         n = get_config(a).param_count()
         assert lo <= n <= hi, (a, n)

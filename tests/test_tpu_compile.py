@@ -133,3 +133,42 @@ def test_serve_cross_launch(one_chip, d):
                                        sharding=one_chip) for m in (26, 8, 512))
     _compile(lambda Xq, Xs, *hs: pw_ops.kernel_matmat_multi_rows(
         spec, Xq, Xs, hs, interpret=False), Xq, Xs, *heads)
+
+
+@pytest.mark.parametrize("fold", [True, False])
+def test_landmark_attention_launch(one_chip, fold):
+    """mellum2's full-layer decode launch: 32 sessions × 4 KV heads, 8
+    query heads a group, c = 512 landmarks, d = 128, the fold aliasing UV,
+    U1 and the offset in place; and the read alone (m = 200 queries)."""
+    from repro.kernels.landmark_attention import kernel as lm_kernel
+    n, g, c, d = (128, 8, 512, 128) if fold else (1, 200, 512, 128)
+    g = -(-g // 8) * 8
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    factors = (f32(n, g, d), f32(n, c, d), f32(n, c, d), f32(n, 1, c),
+               f32(n, 1, 1))
+    extra = (f32(n, c, c), f32(n, 1, d), f32(n, 1, d)) if fold else ()
+    text = _compile(lambda *a: lm_kernel.landmark_attention_padded(*a),
+                    *factors, *extra)
+    assert '"kernel":"landmark_attention"' in text.replace(" ", "") \
+        .replace("\n", "")
+
+
+@pytest.mark.parametrize("tokens", [32, 4096])
+def test_dropless_moe_served(one_chip, tokens):
+    """The served MoE at mellum2's widths (64 experts of 2304 × 896, top-8):
+    the grouped products over the sorted assignments compile for the chip,
+    at a decode step's 32 tokens and a prefill block's 4096."""
+    from repro.configs import get_config
+    from repro.models import moe as M
+    cfg = get_config("mellum2-12b-a2.5b")
+    p = jax.eval_shape(lambda k: M.init_moe(k, cfg), jax.random.PRNGKey(0))
+    p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                    sharding=one_chip), p)
+    x = jax.ShapeDtypeStruct((1, tokens, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+    text = jax.jit(lambda p, x: M.moe_ffn_served(p, cfg, x)).lower(
+        p, x).compile().as_text()
+    assert "ragged" in text
