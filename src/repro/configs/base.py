@@ -72,7 +72,7 @@ class ModelConfig:
     first_k_dense: int = 0
     dense_d_ff: int = 0
     # training dispatch only: gather | shard_map (serving is dropless,
-    # ``moe.moe_ffn_served``, one path)
+    # ``moe.moe_ffn_served``, dense or grouped by the call's shape)
     moe_impl: str = "gather"
 
     # --- MLA (deepseek) ---

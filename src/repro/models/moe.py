@@ -13,16 +13,31 @@ Dispatch is the static-shape sort-based gather path (TPU-native; no dense
 Shared experts (deepseek's 1, qwen's 4) are a plain dense MLP of width
 n_shared * moe_d_ff added unconditionally.
 
-Serving (prefill and decode, ``moe_ffn_served``) drops nothing: the T*k
-assignments are sorted by expert and each weight is one grouped product
-(``jax.lax.ragged_dot``) over the sorted rows, so every expert takes however
-many tokens were routed to it.  It also returns what it dispatched: the
-group sizes, the experts each token was routed to, and the rows the grouped
-products delivered to the combine (a caller counts the assignments dropped
-as those routed less those delivered).  Its router runs at
+Serving (prefill and decode, ``moe_ffn_served``) drops nothing, and
+``_dropless_compute`` is its one entry point.  It picks one of two regimes
+from the shapes of the call:
+
+- grouped (span ``moe.grouped``): the T*k assignments are sorted by expert
+  and each weight is one grouped product (``jax.lax.ragged_dot``) over the
+  sorted rows, so every expert takes however many tokens were routed to it.
+- dense (span ``moe.dense``), when T <= ``DENSE_MAX_TOKENS`` and T*k >= E:
+  every expert runs on every token of the call as XLA dots over the whole
+  weight bank, and the combine selects the routed (token, expert) pairs with
+  the routing mask.  Exact, since top-k picks distinct experts, so no
+  expert has more than T rows.  At T <= 128 a weight element meets at most
+  128 rows, half the v5e's ≈ 240 FLOPs per HBM byte, so the pass stays
+  bound by reading the weights; and at T*k >= E the grouped products would
+  touch at least 1 − 1/e of the experts, so the dense pass reads little more.
+  An XLA dot also takes a scanned layer's slice of the stacked weights as a
+  fused operand, where the grouped product (a Mosaic call) copies it whole.
+
+Both return what they dispatched: the group sizes, the experts each token
+was routed to (``moe_ffn_served``), and the routed assignments whose expert
+output reached the combine non-zero (a caller counts the assignments dropped
+as those routed less those delivered).  The served router runs at
 ``Precision.HIGHEST``: the router weights are f32, and a one-pass bf16
-product would move near-tied experts.  The served path has one
-implementation; ``moe_impl`` selects the training path only.
+product would move near-tied experts.  ``moe_impl`` selects the training
+path only.
 
 ``shard_map`` variant (moe_impl='shard_map'): the same algorithm with the
 expert GEMMs under an explicit mesh-axis shard_map so the all-to-all is
@@ -36,7 +51,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.core.instrument import span
 from repro.models import layers as L
+
+#: the most tokens a served call runs densely over every expert: half the
+#: v5e's ≈ 240 FLOPs per HBM byte (197 TFLOP/s over 819 GB/s)
+DENSE_MAX_TOKENS = 128
 
 
 def init_moe(key: jax.Array, cfg: ModelConfig) -> dict:
@@ -114,9 +134,23 @@ def _dispatch_compute(params: dict, cfg: ModelConfig, xf: jnp.ndarray,
 
 def _dropless_compute(params: dict, cfg: ModelConfig, xf: jnp.ndarray,
                       w: jnp.ndarray, idx: jnp.ndarray):
-    """Grouped dispatch with no capacity. xf: (T, d) -> ((T, d), (E,) group
-    sizes, rows delivered: the assignments whose expert output reached the
-    combine)."""
+    """The served MoE with no capacity. xf: (T, d) -> ((T, d), (E,) group
+    sizes, rows delivered: the routed assignments whose expert output
+    reached the combine non-zero).  Dense over every expert when the call
+    has at most ``DENSE_MAX_TOKENS`` tokens (the pass stays bound by the
+    weight reads) and T*k >= E (the grouped products would touch most
+    experts anyway); grouped products otherwise."""
+    T = xf.shape[0]
+    if T <= DENSE_MAX_TOKENS and T * cfg.moe_top_k >= cfg.n_experts:
+        return _dense_compute(params, cfg, xf, w, idx)
+    return _grouped_compute(params, cfg, xf, w, idx)
+
+
+@span("moe.grouped")
+def _grouped_compute(params: dict, cfg: ModelConfig, xf: jnp.ndarray,
+                     w: jnp.ndarray, idx: jnp.ndarray):
+    """``_dropless_compute`` as one ``ragged_dot`` per weight over the
+    assignments sorted by expert."""
     T, d = xf.shape
     E, k = cfg.n_experts, cfg.moe_top_k
     dt = cfg.cdtype
@@ -137,6 +171,34 @@ def _dropless_compute(params: dict, cfg: ModelConfig, xf: jnp.ndarray,
     vals = ob.astype(jnp.float32) * w.reshape(-1)[order][:, None]
     out = jnp.zeros((T, d), jnp.float32).at[tok].add(vals)
     return out.astype(dt), sizes, delivered
+
+
+@span("moe.dense")
+def _dense_compute(params: dict, cfg: ModelConfig, xf: jnp.ndarray,
+                   w: jnp.ndarray, idx: jnp.ndarray):
+    """``_dropless_compute`` as every expert on every token, one XLA dot per
+    weight over the whole bank, rounded where the grouped products round;
+    the combine keeps the routed (token, expert) pairs alone."""
+    E = cfg.n_experts
+    dt = cfg.cdtype
+    x = xf.astype(dt)
+
+    def expand(wname):
+        return jnp.einsum("td,edf->etf", x, params[wname].astype(dt),
+                          preferred_element_type=jnp.float32)
+
+    h = (jax.nn.silu(expand("wi_gate")) * expand("wi_up")).astype(dt)
+    ob = jnp.einsum("etf,efd->etd", h, params["wo"].astype(dt),
+                    preferred_element_type=dt)               # (E, T, d)
+    hit = idx[None] == jnp.arange(E, dtype=idx.dtype)[:, None, None]
+    routed = jnp.any(hit, axis=-1)                           # (E, T)
+    weight = jnp.sum(jnp.where(hit, w[None], 0.0), axis=-1)  # (E, T)
+    # select, never multiply by 0: an unrouted expert's inf stays out
+    vals = jnp.where(routed[..., None],
+                     ob.astype(jnp.float32) * weight[..., None], 0.0)
+    sizes = jnp.bincount(idx.reshape(-1), length=E).astype(jnp.int32)
+    delivered = jnp.sum(routed & jnp.any(ob != 0, axis=-1), dtype=jnp.int32)
+    return jnp.sum(vals, axis=0).astype(dt), sizes, delivered
 
 
 def moe_ffn_served(params: dict, cfg: ModelConfig, x: jnp.ndarray):

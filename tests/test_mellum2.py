@@ -304,3 +304,89 @@ def test_dropless_moe_when_one_expert_takes_every_token():
                                rtol=1e-5, atol=1e-5)
     dropped_path, _ = jax.jit(M.moe_ffn, static_argnums=1)(p, cfg, x)
     assert float(jnp.max(jnp.abs(dropped_path[0] - want))) > 1e-3
+
+
+def _top_k_loop(p, cfg, xf):
+    """Each token through its top-k experts one at a time, weighted by its
+    renormalised router probabilities."""
+    probs = jax.nn.softmax(jnp.matmul(xf, p["router"],
+                                      precision=jax.lax.Precision.HIGHEST))
+    w, idx = jax.lax.top_k(probs, cfg.moe_top_k)
+    w = w / jnp.sum(w, axis=-1, keepdims=True)
+
+    def one_token(xt, wt, it):
+        y = jnp.zeros((cfg.d_model,))
+        for j in range(cfg.moe_top_k):
+            h = jax.nn.silu(xt @ p["wi_gate"][it[j]]) \
+                * (xt @ p["wi_up"][it[j]])
+            y = y + wt[j] * (h @ p["wo"][it[j]])
+        return y
+
+    return jax.jit(jax.vmap(one_token))(xf, w, idx)
+
+
+def _served(p, cfg, x):
+    """``moe_ffn_served`` under a fresh jit, so a patched compute is traced."""
+    return jax.jit(lambda p, x: M.moe_ffn_served(p, cfg, x))(p, x)
+
+
+@pytest.mark.parametrize("tokens,path", [
+    (16, "dense"),          # T <= 128 and T·k = 64 >= E = 16
+    (160, "grouped"),       # past the dense pass's 128 tokens
+    (3, "grouped"),         # T·k = 12 < E: most experts untouched
+])
+def test_served_moe_paths_match_the_loop(monkeypatch, tokens, path):
+    """The served MoE takes the dense pass over every expert or the grouped
+    products from the call's shape (its span says which), and both equal
+    the per-token top-k loop and report the same dispatch; an expert whose
+    output is zero counts its routed rows as not delivered in both."""
+    cfg = SMOKE
+    p = jax.jit(M.init_moe, static_argnums=1)(jax.random.PRNGKey(8), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(tokens), (1, tokens,
+                                                       cfg.d_model))
+    # token 0's first expert outputs zeros: its rows are routed, not delivered
+    silent = int(jnp.argmax(x[0, 0] @ p["router"]))
+    p["wo"] = p["wo"].at[silent].set(0.0)
+    text = jax.jit(lambda p, x: M.moe_ffn_served(p, cfg, x)).lower(
+        p, x).as_text(debug_info=True)               # scopes in locations
+    other = {"dense": "grouped", "grouped": "dense"}[path]
+    assert f"moe.{path}" in text and f"moe.{other}" not in text
+    want = np.asarray(_top_k_loop(p, cfg, x[0]))
+
+    runs = {}
+    for name, fn in (("dense", M._dense_compute),
+                     ("grouped", M._grouped_compute)):
+        monkeypatch.setattr(M, "_dropless_compute", fn)
+        runs[name] = _served(p, cfg, x)
+        np.testing.assert_allclose(np.asarray(runs[name][0][0]), want,
+                                   rtol=1e-5, atol=1e-5, err_msg=name)
+    dense, grouped = runs["dense"][1], runs["grouped"][1]
+    for key in ("sizes", "experts", "delivered"):
+        np.testing.assert_array_equal(np.asarray(dense[key]),
+                                      np.asarray(grouped[key]), err_msg=key)
+    assert int(dense["sizes"][silent]) >= 1
+    assert int(dense["delivered"]) == (tokens * cfg.moe_top_k
+                                       - int(dense["sizes"][silent]))
+
+
+def test_dense_moe_selects_routed_pairs():
+    """An expert no token is routed to, with an infinite ``wo``: the dense
+    pass computes its output (inf or NaN) for every token, and the combine
+    must select the routed pairs with the mask, not multiply the rest by 0,
+    for the output to stay equal to the per-token loop."""
+    cfg = SMOKE
+    E = cfg.n_experts
+    p = jax.jit(M.init_moe, static_argnums=1)(jax.random.PRNGKey(9), cfg)
+    p["router"] = p["router"].at[:, E - 1].add(-50.0)
+    p["wo"] = p["wo"].at[E - 1].set(jnp.inf)
+    T = 16
+    # positive inputs: expert E − 1's logit is the lowest for every token
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(10), (1, T,
+                                                          cfg.d_model)))
+    got, dispatch = _served(p, cfg, x)
+    assert int(dispatch["sizes"][E - 1]) == 0
+    assert int(dispatch["delivered"]) == T * cfg.moe_top_k
+    want = np.asarray(_top_k_loop(p, cfg, x[0]))
+    assert np.all(np.isfinite(want))
+    np.testing.assert_allclose(np.asarray(got[0]), want, rtol=1e-5,
+                               atol=1e-5)

@@ -6,6 +6,8 @@ The topology is described inside a module-scoped fixture, never at import:
 only the worker that runs these tests loads the TPU compiler, so the other
 test workers collect the same tests and never contend for it.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -156,19 +158,73 @@ def test_landmark_attention_launch(one_chip, fold):
         .replace("\n", "")
 
 
+def _moe_shapes(cfg, sharding, lead=()):
+    from repro.models import moe as M
+    p = jax.eval_shape(lambda k: M.init_moe(k, cfg), jax.random.PRNGKey(0))
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        lead + a.shape, a.dtype, sharding=sharding), p)
+
+
 @pytest.mark.parametrize("tokens", [32, 4096])
 def test_dropless_moe_served(one_chip, tokens):
-    """The served MoE at mellum2's widths (64 experts of 2304 × 896, top-8):
-    the grouped products over the sorted assignments compile for the chip,
-    at a decode step's 32 tokens and a prefill block's 4096."""
+    """The served MoE at mellum2's widths (64 experts of 2304 × 896, top-8)
+    compiles for the chip: a decode step's 32 tokens run densely over every
+    expert, with no grouped product left; a prefill block's 4096 run the
+    grouped products over the sorted assignments."""
     from repro.configs import get_config
     from repro.models import moe as M
     cfg = get_config("mellum2-12b-a2.5b")
-    p = jax.eval_shape(lambda k: M.init_moe(k, cfg), jax.random.PRNGKey(0))
-    p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                                    sharding=one_chip), p)
+    p = _moe_shapes(cfg, one_chip)
     x = jax.ShapeDtypeStruct((1, tokens, cfg.d_model), jnp.bfloat16,
                              sharding=one_chip)
     text = jax.jit(lambda p, x: M.moe_ffn_served(p, cfg, x)).lower(
         p, x).compile().as_text()
-    assert "ragged" in text
+    assert ("ragged" in text) == (tokens > M.DENSE_MAX_TOKENS)
+
+
+def _loop_bodies(text):
+    """{name: instruction lines} of the computations a ``while`` runs as its
+    body, in compiled HLO text; a fusion's own computation is not one."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None and line.startswith("  "):
+            comps[name].append(line)
+    bodies = set(re.findall(r"body=%([\w.\-]+)", text))
+    return {n: comps[n] for n in bodies}
+
+
+def test_dense_moe_reads_scanned_weights_in_place(one_chip):
+    """A decode turn's MoE as ``stack_decode`` runs it: two pattern repeats
+    of four MoE layers scanned over weights stacked per repeat, 32 tokens,
+    inside a loop of steps, at mellum2's widths.  No top-level fusion or
+    copy of a loop body writes a layer's whole expert bank
+    (bf16[64,2304,896] / bf16[64,896,2304]): the dots read the repeat's
+    slice of the stacked weights in place."""
+    from repro.configs import get_config
+    from repro.models import moe as M
+    cfg = get_config("mellum2-12b-a2.5b")
+    stacked = tuple(_moe_shapes(cfg, one_chip, lead=(2,)) for _ in range(4))
+    x = jax.ShapeDtypeStruct((1, 32, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def turn(params, x):
+        def repeat(h, layers):
+            for p in layers:
+                out, _ = M.moe_ffn_served(p, cfg, h)
+                h = h + out
+            return h, None
+
+        return jax.lax.fori_loop(
+            0, 4, lambda _, h: jax.lax.scan(repeat, h, params)[0], x)
+
+    text = jax.jit(turn).lower(stacked, x).compile().as_text()
+    bodies = _loop_bodies(text)
+    assert bodies
+    bank = re.compile(r"= bf16\[64,(2304,896|896,2304)\]\S* (fusion|copy)\(")
+    copies = [ln.strip()[:120] for lines in bodies.values() for ln in lines
+              if bank.search(ln)]
+    assert not copies, copies
