@@ -7,10 +7,6 @@ features -> 10-NN classification of held-out points, plus approximate
 spectral clustering — the paper's two applications, on one synthetic
 dataset, all through the public API.
 """
-import sys
-
-sys.path.insert(0, "benchmarks") if "benchmarks" not in sys.path else None
-
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -17,8 +17,6 @@ from repro.core.selection import (LeveragePolicy, SelectionPolicy,
                                   get_policy, register_policy,
                                   registered_policies, residual_column_norms)
 # per-spec streaming calibration lives in repro.kernels.pairwise.calibrate
-# (NOT re-exported here: benchmarks/common.py has an unrelated eta-targeted
-# calibrate_sigma and the two must never be import-confused)
 from repro.core.leverage import (column_leverage_scores,
                                  column_leverage_scores_gram,
                                  orthonormal_basis, pinv, row_coherence,

@@ -45,9 +45,7 @@ panels regardless of how ``jax.lax.scan`` re-executes the traced body):
 - ``blocks`` / ``columns`` / ``diags`` / ``fulls`` : direct-access calls
 
 Used by the parity/entry-count tests (fast_model + streaming error must stay
-≤ 2 sweeps; the fused ``fast_model_with_error`` at exactly 1) and by
-``benchmarks/bench_time.py --streaming`` to print measured entry counts
-alongside wall time.
+≤ 2 sweeps; the fused ``fast_model_with_error`` at exactly 1).
 """
 from __future__ import annotations
 

@@ -46,10 +46,10 @@ so jit caches key on one spec instance per parameter set::
     K = PairwiseKernel(X, spec, use_pallas=True)      # full fused-sweep path
 
 That is the whole integration: the operator layer, the sweep-engine routing
-(``pallas_fused`` / ``pallas_fused_sharded`` / ``panel``), CUR, eig, and the
-benchmarks all pick the new kernel up through the registry with zero
-per-call-site changes.  ``entry_fn`` must be elementwise and produce an SPSD
-kernel for the intended statistic — the registry does not check positivity.
+(``pallas_fused`` / ``pallas_fused_sharded`` / ``panel``), CUR and eig all
+pick the new kernel up through the registry with zero per-call-site
+changes.  ``entry_fn`` must be elementwise and produce an SPSD kernel for
+the intended statistic — the registry does not check positivity.
 """
 from __future__ import annotations
 
@@ -309,12 +309,12 @@ def get_spec(name: str, **params) -> KernelSpec:
 
 
 def registered_kernels() -> Tuple[str, ...]:
-    """Registered kernel names, sorted (the benchmark/test sweep order)."""
+    """Registered kernel names, sorted (the test sweep order)."""
     return tuple(sorted(_REGISTRY))
 
 
 # Parameterizations that keep entries O(1) on standardized/unit-scale data —
-# the single source the registry-sweeping benchmarks and parity tests share
+# the single source the registry-sweeping parity and conformance tests share
 # (polynomial is normalized by 1/d, the sklearn convention).  Kernels not
 # listed (user-registered specs) fall back to their factory defaults, so a
 # custom registration never breaks the registry sweeps.

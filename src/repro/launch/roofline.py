@@ -2,9 +2,9 @@
 
 Three terms per (arch, shape, mesh), in seconds:
 
-    compute    = HLO_FLOPs / (chips * PEAK_FLOPS)
-    memory     = HLO_bytes / (chips * HBM_BW)
-    collective = collective_bytes / (chips * LINK_BW)
+    compute    = HLO_FLOPs / (chips * V5E.peak_flops)
+    memory     = HLO_bytes / (chips * V5E.hbm_bw)
+    collective = collective_bytes / (chips * V5E.link_bw)
 
 Sources:
 - ``compiled.cost_analysis()`` -> 'flops' and 'bytes accessed'.  The compiled
@@ -15,9 +15,9 @@ Sources:
   / all-to-all / collective-permute op.  Bytes are computed from the result
   shape (for all-gather: the gathered output; for reduce-scatter: the input =
   output * group); this is the volume that crosses links per chip up to the
-  ring-algorithm factor 2(g-1)/g ~ 2 which we fold into EFFECTIVE_LINK_BW.
+  ring-algorithm factor 2(g-1)/g ~ 2 which we fold into ``V5E.link_bw``.
 
-v5e hardware constants (per chip):
+The peaks are a ``HardwareProfile``; ``V5E`` holds the v5e per-chip ones.
 """
 from __future__ import annotations
 
@@ -25,8 +25,6 @@ import dataclasses
 import json
 import re
 from typing import Dict, List, Optional
-
-from repro.kernels.pairwise.kernel import launch_work
 
 
 @dataclasses.dataclass(frozen=True)
@@ -43,37 +41,6 @@ class HardwareProfile:
 
 #: v5e per-chip peaks (bf16 MXU; Google Cloud documentation, "TPU v5e")
 V5E = HardwareProfile("v5e", peak_flops=197e12, hbm_bw=819e9, link_bw=50e9)
-
-#: TPU peaks keyed by ``jax.Device.device_kind``.  A TPU that is not listed
-#: is an error, never a default.
-TPU_PROFILES = {"TPU v5 lite": V5E}
-
-#: an honest CI profile: interpret-mode Pallas on a shared CPU runner.  The
-#: numbers are order-of-magnitude host figures (a few AVX cores, DDR
-#: bandwidth, loopback "links") — the point is that CPU reports say so,
-#: rather than scoring a CPU wall-clock against a 197-TFLOP/s TPU.
-CPU_INTERPRET = HardwareProfile("cpu-interpret", peak_flops=2e11,
-                                hbm_bw=2e10, link_bw=1e10)
-
-
-def default_profile() -> HardwareProfile:
-    """The attached device's peaks: ``TPU_PROFILES[device_kind]`` on a TPU
-    (an unknown TPU raises), ``CPU_INTERPRET`` on any other backend."""
-    import jax
-    dev = jax.devices()[0]
-    if dev.platform != "tpu":
-        return CPU_INTERPRET
-    if dev.device_kind not in TPU_PROFILES:
-        raise ValueError(f"no peaks recorded for TPU kind {dev.device_kind!r}; "
-                         f"known: {sorted(TPU_PROFILES)}")
-    return TPU_PROFILES[dev.device_kind]
-
-
-# Back-compat module aliases (v5e values); new code should pass a
-# ``HardwareProfile`` explicitly.
-PEAK_FLOPS = V5E.peak_flops  # bf16 FLOP/s
-HBM_BW = V5E.hbm_bw          # bytes/s
-LINK_BW = V5E.link_bw        # bytes/s per ICI link (ring-collective effective)
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -285,60 +252,6 @@ def analyze(compiled, hlo_text: str, *, arch: str, shape, cfg, mesh_name: str,
         bytes_per_chip=float(mstats.get("bytes_per_chip", 0.0)),
     )
     return r.finalize(profile)
-
-
-# ---------------------------------------------------------------------------
-# kernel-layer scoring: the pairwise sweep template's per-launch roofline
-# ---------------------------------------------------------------------------
-
-def achieved_vs_roofline(spec, shape, mesh=None, *, measured_s: float,
-                         m_total: int, l1_route: Optional[str] = None,
-                         segments: int = 0,
-                         profile: Optional[HardwareProfile] = None) -> dict:
-    """Score one measured pairwise launch against its modeled roofline.
-
-    ``shape`` is ``(nr, nc, d)`` for the launch; ``mesh`` (optional) divides
-    the modeled work across its devices like the sharded sweep does.  The
-    work is ``launch_work``'s, the count each launch also records in its
-    metadata.
-    Returns a JSON-ready report: modeled compute/memory seconds under
-    ``profile`` (``default_profile()`` when omitted — so CI's CPU-interpret
-    numbers are scored against CPU peaks, not v5e's), the binding term, and
-    ``achieved_frac`` = roofline_s / measured_s (1.0 means the launch runs
-    at the modeled roof; interpret-mode values are tiny and that is the
-    honest answer).
-    """
-    prof = default_profile() if profile is None else profile
-    nr, nc, d = (int(x) for x in shape)
-    chips = 1
-    if mesh is not None and getattr(mesh, "devices", None) is not None:
-        chips = max(1, int(mesh.devices.size))
-    work = launch_work(spec, nr, nc, d, int(m_total), l1_route=l1_route,
-                       segments=segments)
-    compute_s = (work["mxu_flops"] + work["vpu_flops"]) / (
-        chips * prof.peak_flops)
-    memory_s = work["hbm_bytes"] / (chips * prof.hbm_bw)
-    roofline_s = max(compute_s, memory_s)
-    return {
-        "kernel": spec.name,
-        "stat": spec.stat,
-        "precision": getattr(spec, "precision", "f32"),
-        "l1_route": l1_route,
-        "shape": [nr, nc, d],
-        "m_total": int(m_total),
-        "chips": chips,
-        "profile": prof.name,
-        "mxu_gflops": work["mxu_flops"] / 1e9,
-        "vpu_gflops": work["vpu_flops"] / 1e9,
-        "hbm_gbytes": work["hbm_bytes"] / 1e9,
-        "compute_s": float(compute_s),
-        "memory_s": float(memory_s),
-        "bottleneck": "compute" if compute_s >= memory_s else "memory",
-        "roofline_s": float(roofline_s),
-        "measured_s": float(measured_s),
-        "achieved_frac": float(roofline_s / measured_s)
-        if measured_s > 0 else 0.0,
-    }
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,6 @@
 """Where the persistent XLA compilation cache lives.
 
-Entry points that compile for a device (``chip_smoke.py``,
-``repro.launch.serve_kernel``, ``benchmarks.run``) call
+Entry points that compile for a device (``repro.launch.serve_kernel``) call
 ``enable_compile_cache()`` before their first compile:
 
 - ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself, nothing is set here;

@@ -1,7 +1,8 @@
 """mellum2-12b-a2.5b at its CPU twin (``SMOKE``, float32, seeded random
-weights) against the plain reference ``models/reference_mellum2.py``, and
-the parts it forced: YaRN on the full layers, the landmark fold, the offset
-guard, the dropless served MoE and the sliding ring."""
+weights) against the plain reference ``bench/reference_mellum2.py`` (the
+benchmark's oracle, which imports nothing of the program), and the parts it
+forced: YaRN on the full layers, the landmark fold, the offset guard, the
+dropless served MoE and the sliding ring."""
 import dataclasses
 import math
 
@@ -10,12 +11,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from bench import reference_mellum2 as ref
 from repro.configs import get_smoke
 from repro.core.sketched_attention import build_landmark_state
 from repro.kernels.landmark_attention import ops as lm_ops
 from repro.models import layers as L
 from repro.models import moe as M
-from repro.models import reference_mellum2 as ref
 from repro.models.model import build_model
 
 SMOKE = get_smoke("mellum2-12b-a2.5b")

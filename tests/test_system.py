@@ -1,6 +1,13 @@
-"""Whole-system sanity: public API imports + the registry covers the
-assigned 40-cell matrix."""
+"""Whole-system sanity: public API imports, the registry covers the
+assigned 40-cell matrix, and the benchmark's oracles stand apart from the
+program."""
+import ast
 import importlib
+import pathlib
+
+import pytest
+
+BENCH = pathlib.Path(__file__).resolve().parents[1] / "bench"
 
 
 def test_public_imports():
@@ -59,3 +66,17 @@ def test_deepseek_active_params():
     cfg = get_config("deepseek-v3-671b")
     na = cfg.active_param_count()
     assert 34e9 <= na <= 40e9, na
+
+
+@pytest.mark.parametrize("name", ["reference.py", "reference_mellum2.py"])
+def test_bench_reference_imports_nothing_of_the_program(name):
+    """The plain references that decide ``correct`` (and that the tests
+    check the program against) must not share code with the program."""
+    tree = ast.parse((BENCH / name).read_text())
+    found = []
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            found += [a.name for a in node.names]
+        elif isinstance(node, ast.ImportFrom) and node.module:
+            found.append(node.module)
+    assert not [m for m in found if m.split(".")[0] == "repro"], found
