@@ -48,10 +48,11 @@ from repro.core.kernelop import PairwiseKernel
 from repro.kernels.pairwise import specs as pw_specs
 
 # smoke shape: big enough that Θ(n²) separates from Θ(n·c), Θ(128·n)
-# padded-sketch slabs, and the launch template's constant (128 × 128) VMEM
-# tiles — n²/2 must exceed all three — yet small enough that tracing is
-# instant.  n=512 puts the threshold at 131072 elements vs 65536 for the
-# largest legitimate slab (a right-hand side padded to 128 lanes).
+# padded-sketch slabs, and the launch template's VMEM tiles (128 × 128 at
+# this n: a sweep tile side is at most a quarter of its axis) — n²/2 must
+# exceed all three — yet small enough that tracing is instant.  n=512 puts
+# the threshold at 131072 elements vs 65536 for the largest legitimate slab
+# (a right-hand side padded to 128 lanes).
 SMOKE_N = 512
 SMOKE_D = 4
 SMOKE_C = 12

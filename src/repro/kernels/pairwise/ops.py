@@ -141,8 +141,9 @@ def kernel_matmat_multi_rows(spec: KernelSpec, Xr: jnp.ndarray,
 
     The gather-based fast path of the sweep engine: the caller materializes
     its row slab ``Xr = X[r0:r1]`` and passes the full column points ``Xc``,
-    so only that slab's (128 × 128) kernel tiles are ever computed — once,
-    in VMEM — and contracted against every right-hand side.  Prefer
+    so only that slab's kernel tiles are ever computed — once, in VMEM, at
+    the tile ``kernel.sweep_tile`` picks — and contracted against every
+    right-hand side.  Prefer
     ``kernel_matmat_multi_slab`` when the slab is a contiguous range of
     ``Xc`` — it addresses the slab in-launch instead of copying it.
 

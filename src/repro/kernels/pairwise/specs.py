@@ -5,7 +5,7 @@ only needs SPSD kernel entries computed on the fly from the data points.  A
 ``KernelSpec`` captures exactly what varies between kernels so that ONE tiled
 Pallas sweep template (``repro.kernels.pairwise.kernel``) serves all of them:
 
-- ``stat``: which pairwise statistic a (BLOCK_R, BLOCK_C) tile computes from
+- ``stat``: which pairwise statistic a kernel tile computes from
   the point tiles — ``'sqdist'`` (‖x−y‖₂², MXU cross product + VPU combine),
   ``'dot'`` (xᵀy, pure MXU), or ``'l1dist'`` (‖x−y‖₁: the MXU sign-split
   route of ``repro.kernels.pairwise.signsplit`` when the operator has a

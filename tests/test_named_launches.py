@@ -223,7 +223,7 @@ def test_launch_work_hand_values(stat, nr, d, ms, landmarks, segments,
     rec = pw_kernel.launch_record("k", spec, nr, nr, d, ms, edges, landmarks)
     expected = {"kernel": "k", "mxu_flops": str(mxu_flops),
                 "entries": str(nr * (nr + landmarks)), "precision": "f32",
-                "passes": "not counted"}
+                "passes": "not counted", "tile": "128x128"}
     if landmarks:
         expected["landmarks"] = str(landmarks)
     assert rec == expected
@@ -246,7 +246,7 @@ def test_launch_without_landmarks_is_unchanged(name, fn, nr, result,
     assert rec == {"kernel": name,
                    "mxu_flops": str(2 * nr * 256 * (16 + 128)),
                    "entries": str(nr * 256), "precision": "f32",
-                   "passes": "not counted"}
+                   "passes": "not counted", "tile": "128x128"}
     sig = re.search(r"= (\S+) custom-call\(.*?operand_layout_constraints="
                     r"\{(.*?)\}, frontend", lowered.as_text(dialect="hlo"))
     assert sig.groups() == (result, operands)

@@ -2,6 +2,9 @@
 the shared Pallas sweep template vs its independent dense oracle, the
 PairwiseKernel operator protocol, and registry round-trips (including a
 user-registered custom kernel riding the full fused machinery)."""
+import json
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,9 +14,10 @@ from repro.core import spsd
 from repro.core import sweep as sw
 from repro.core.instrument import CountingOperator
 from repro.core.kernelop import LinearKernel, PairwiseKernel, RBFKernel
+from repro.kernels.pairwise import kernel as pw_kernel
 from repro.kernels.pairwise import ops as pw_ops
 from repro.kernels.pairwise import ref as pw_ref
-from repro.kernels.pairwise import specs
+from repro.kernels.pairwise import signsplit, specs
 
 # the shared registry-sweep parameterization (specs.suggested_params keeps
 # entries O(1) on unit-scale data; custom kernels get factory defaults)
@@ -216,6 +220,105 @@ def test_fast_model_end_to_end_precision(precision):
         PairwiseKernel(X, _spec("rbf"), use_pallas=False), f32_ap,
         method="dense"))
     assert ref_err <= f32_err + 5e-2
+
+
+@pytest.mark.parametrize("nr,nc,d,inner,ms,landmarks,expected", [
+    # analysis/jaxpr_check's smoke shape: n = 512, c = 12 landmarks
+    (512, 512, 4, 4, (128,), 128, (128, 128)),
+    # a KernelServer bucket: 64 query rows against 512 landmarks, 3 heads
+    (128, 512, 16, 16, (128, 128, 512), 0, (128, 128)),
+    # susy-rbf.certify's sweep: n = 2^19, d = 18, probes and C of 512
+    (2 ** 19, 2 ** 19, 18, 18, (128,), 512, (1024, 1024)),
+    # mnist-rbf.certify's: n = 2^18, d = 784
+    (2 ** 18, 2 ** 18, 784, 784, (128,), 512, (512, 512)),
+    # a sign-split table of MAX_SLOTS slots: 2·B·d = 8192 inner width
+    (2 ** 16, 2 ** 16, 128, 2 * signsplit.MAX_SLOTS, (128,), 0, (128, 128)),
+], ids=["jaxpr-smoke", "serve-bucket", "susy", "mnist",
+        "signsplit-max-slots"])
+def test_sweep_tile_rule(nr, nc, d, inner, ms, landmarks, expected):
+    """The fused launch's grid tile: small launches stay at 128 × 128,
+    the certify cells' sweeps take wider ones (narrower at d = 784 than at
+    d = 18), every side is on the ladder, divides its padded axis and
+    takes at most a quarter of it, and the step fits the VMEM budget."""
+    tile = pw_kernel.sweep_tile(nr, nc, d, inner, ms, landmarks)
+    assert tile == expected
+    for side, n in zip(tile, (nr, nc)):
+        assert side in pw_kernel.TILE_LADDER and n % side == 0
+        assert side == 128 or 4 * side <= n
+    assert (pw_kernel.sweep_vmem_bytes(*tile, d, inner, ms, landmarks)
+            <= pw_kernel.VMEM_BUDGET < pw_kernel.VMEM_LIMIT)
+
+
+def _launch_record(fn, *shapes):
+    """The launch record of the one Pallas launch in ``fn`` lowered for TPU
+    (no chip needed: the record is in the custom call's HLO text)."""
+    hlo = (jax.jit(fn).trace(*shapes).lower(lowering_platforms=("tpu",))
+           .as_text(dialect="hlo"))
+    [rec] = set(re.findall(r"kernel_metadata=(\{[^{}]*\})", hlo))
+    return json.loads(rec)
+
+
+#: a rectangular launch whose padded shapes, (1024 + 2048) × 18 points and
+#: right-hand sides of 64 and 128 columns, select a tile wider than 128
+WIDE = dict(nr=1000, nc=2000, d=18, ms=(64, 128))
+WIDE_ROUTES = {"sqdist": "rbf", "dot": "linear",
+               "l1dist-signsplit": "laplacian"}
+
+
+@pytest.mark.parametrize("landmarks", [0, 200], ids=["products", "gather"])
+@pytest.mark.parametrize("precision", specs.PRECISIONS)
+@pytest.mark.parametrize("route", list(WIDE_ROUTES))
+def test_fused_launch_at_a_wide_tile_vs_ref(route, precision, landmarks):
+    """The fused launch at a tile picked wider than 128 × 128 (Xr ≠ Xc,
+    two right-hand sides, with and without C from 200 landmark points,
+    padded to 256) against the dense oracles, and its launch record: the
+    tile it ran with, and the work counted at launch shapes."""
+    name = WIDE_ROUTES[route]
+    d = WIDE["d"]
+    spec = _spec(name, d).with_precision(precision)
+    rng = np.random.default_rng(21)
+    if route == "l1dist-signsplit":            # a lattice, so a plan exists
+        pts = rng.integers(-4, 5, size=(WIDE["nr"] + WIDE["nc"], d)) * 0.5
+    else:
+        pts = rng.normal(size=(WIDE["nr"] + WIDE["nc"], d))
+    pts = jnp.asarray(pts, jnp.float32)
+    Xr, Xc = pts[:WIDE["nr"]], pts[WIDE["nr"]:]
+    plan = signsplit.build_plan(pts) if route == "l1dist-signsplit" else None
+    edges = None if plan is None else plan.edges
+    assert (edges is not None) == (route == "l1dist-signsplit")
+    Vs = tuple(jnp.asarray(rng.normal(size=(WIDE["nc"], m)), jnp.float32)
+               for m in WIDE["ms"])
+    Xl = Xc[:landmarks] if landmarks else None
+    outs = pw_ops.kernel_matmat_multi_rows(spec, Xr, Xc, Vs, edges=edges,
+                                           Xl=Xl)
+    oracle = _spec(name, d)
+    refs = pw_ref.kernel_matmat_multi_rows(oracle, Xr, Xc, Vs)
+    if landmarks:
+        refs = (pw_ref.kernel_block(oracle, Xr, Xl),) + refs
+    assert len(outs) == len(refs)
+    for out, ref in zip(outs, refs):
+        assert_parity(out, ref, tol=PREC_TOL[precision])
+
+    nr, nc, lm = 1024, 2048, 256 if landmarks else 0
+    inner = d if edges is None else 2 * d * plan.segments
+    tile = pw_kernel.sweep_tile(nr, nc, d, inner, (128, 128), lm)
+    assert tile != (128, 128)
+    assert nr % tile[0] == 0 and nc % tile[1] == 0
+    shapes = [jax.ShapeDtypeStruct((nr, d), jnp.float32),
+              jax.ShapeDtypeStruct((nc, d), jnp.float32),
+              jax.ShapeDtypeStruct((nc, 128), jnp.float32),
+              jax.ShapeDtypeStruct((nc, 128), jnp.float32)]
+    if lm:
+        shapes.append(jax.ShapeDtypeStruct((lm, d), jnp.float32))
+    rec = _launch_record(
+        lambda Xr, Xc, *rest: pw_kernel.pairwise_matmat_multi_padded(
+            spec, Xr, Xc, rest[:2], edges=edges,
+            Xl=rest[2] if lm else None), *shapes)
+    width = d if edges is None else 2 * inner        # two contractions
+    entries = nr * (nc + lm)
+    assert rec["tile"] == f"{tile[0]}x{tile[1]}"
+    assert rec["entries"] == str(entries)
+    assert rec["mxu_flops"] == str(2 * entries * width + 2 * nr * nc * 256)
 
 
 # ---------------------------------------------------------------------------

@@ -124,6 +124,34 @@ def test_landmark_sweep_launch(one_chip, launch, kernel, d, precision):
     assert f"(f32[{rows},{C}]" in text          # C first, in the tuple
 
 
+@pytest.mark.parametrize("kernel,n,d,nseg,c", [
+    ("rbf", 2 ** 19, 18, 0, 512),                 # susy-rbf.certify
+    ("rbf", 2 ** 18, 784, 0, 512),                # mnist-rbf.certify
+    ("laplacian-signsplit", 2 ** 16, 18, 9, 512),
+    ("laplacian-signsplit", 2 ** 16, 128, 32, 0),   # MAX_SLOTS slots
+    ("laplacian-vpu", 2 ** 16, 784, 0, 0),
+], ids=["susy", "mnist", "signsplit", "signsplit-max-slots", "vpu-d784"])
+def test_sweep_launch_compiles_at_its_picked_tile(one_chip, kernel, n, d,
+                                                  nseg, c):
+    """The fused sweep at the tile ``sweep_tile`` picks for the certify
+    cells' shapes and for the l1 routes at large n fits the VMEM the launch
+    asks for, and its record names that tile."""
+    spec = specs.rbf(4.0) if kernel == "rbf" else specs.laplacian(1.0 / d)
+    edges = _edges(d, nseg) if nseg else None
+    inner = d if edges is None else 2 * d * nseg
+    tile = pw_kernel.sweep_tile(n, n, d, inner, (128,), c)
+    X = jax.ShapeDtypeStruct((n, d), jnp.float32, sharding=one_chip)
+    V = jax.ShapeDtypeStruct((n, 128), jnp.float32, sharding=one_chip)
+    if c:
+        Xl = jax.ShapeDtypeStruct((c, d), jnp.float32, sharding=one_chip)
+        text = _compile(lambda X, Xl, V: pw_kernel.pairwise_matmat_multi_padded(
+            spec, X, X, (V,), edges=edges, Xl=Xl), X, Xl, V)
+    else:
+        text = _compile(lambda X, V: pw_kernel.pairwise_matmat_multi_padded(
+            spec, X, X, (V,), edges=edges), X, V)
+    assert f'"tile":"{tile[0]}x{tile[1]}"' in text.replace(" ", "")
+
+
 @pytest.mark.parametrize("d", [16, 112, 784])
 def test_serve_cross_launch(one_chip, d):
     """The KernelServer's one launch per bucket: a ragged query block
